@@ -63,7 +63,7 @@ def fig8_sdv_scaling():
         # live check: the packed matvec at this precision, through the
         # core int64 *oracle* (x64 scoped here; the serving kernels run
         # the same wide words as 2-limb int32 — see kernelbench)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             plan = plan_sdv(DSP48E2, w, w)
             wm = jnp.asarray(
                 rng.integers(-(1 << w - 1), 1 << w - 1, (24, 24)))
@@ -88,7 +88,7 @@ def fig9_bseg_scaling():
     for w in range(2, 9):
         est = bseg_conv_unit(128, 8, 16, 1500, w, w, out_per_cycle=8)
         # core int64 oracle timing (x64 scoped; kernels are 2-limb)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             plan = plan_bseg(DSP48E2, w, w)
             taps = jnp.asarray(
                 rng.integers(-(1 << w - 1), 1 << w - 1, (16, 8)))

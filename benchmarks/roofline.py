@@ -77,7 +77,7 @@ def _lower_cost(cfg, shape, mesh):
         with shard_ctx.use_rules(rules):
             compiled = jax.jit(fn, in_shardings=in_sh,
                                donate_argnums=donate).lower(*args).compile()
-    cost = DR.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = DR.collective_bytes(compiled.as_text())
     return {"flops": cost.get("flops", 0.0),
             "bytes": cost.get("bytes accessed", 0.0),

@@ -319,16 +319,17 @@ def sdv_word_spec(plan) -> WordSpec:
                     limbs=2 if wide else 1)
 
 
-def pack_iota(seg, plan: BSEGPlan, *, axis: int):
-    """Pack ``n_i`` unsigned input samples (size-``n_i`` ``axis`` of
-    ``seg``, any integer dtype) into one input factor per position, in
-    the plan's word representation."""
+def pack_iota(samples, plan: BSEGPlan):
+    """Pack ``n_i`` unsigned input samples (a sequence of ``n_i``
+    equal-shape integer arrays, sample ``j`` landing in lane ``j``)
+    into one input factor per position, in the plan's word
+    representation."""
     ws = word_spec(plan)
-    segs = jnp.moveaxis(seg, axis, 0)
-    iota = ws.w_zeros(segs.shape[1:])
+    iota = ws.w_zeros(samples[0].shape)
     for j in range(plan.n_i):
         iota = ws.w_add(iota,
-                        ws.w_shift_left(ws.w_from_i32(segs[j], signed=False),
+                        ws.w_shift_left(ws.w_from_i32(samples[j],
+                                                      signed=False),
                                         j * plan.lane))
     return iota
 

@@ -30,61 +30,62 @@ from . import bseg_common
 
 def _body(plan: BSEGPlan, n_groups: int, n_steps: int, s_out: int,
           x_ref, kap_ref, o_ref, buf_ref, carry_ref):
+    """Every dynamic index is a sublane offset into a ref: the step's
+    input samples, the carry word and the output buffer rows are read
+    and written in place (``[1, bc]`` rows), never sliced out of a
+    loaded value."""
     n_k, n_i = plan.n_k, plan.n_i
-    n_lanes = plan.n_lanes
     ws = bseg_common.word_spec(plan)
+    two_limb = ws.limbs == 2
 
     buf_ref[...] = jnp.zeros_like(buf_ref)
     # carry scratch holds one word per (group, channel); on a 2-limb
     # spec the scratch has a leading (2,) limb-plane axis
-    init_shape = carry_ref.shape[1:] if ws.limbs == 2 else carry_ref.shape
+    init_shape = carry_ref.shape[1:] if two_limb else carry_ref.shape
     carry_ref[...] = ws.w_to_planes(ws.w_full(init_shape, ws.bias_full))
 
-    def read_carry(g):
-        if ws.limbs == 2:
-            return bseg_common.Limbs(carry_ref[0, g], carry_ref[1, g])
-        return carry_ref[g]
+    def row(ref, g):
+        """Word row ``g`` ([1, bc]) of a word-domain ref."""
+        if two_limb:
+            return bseg_common.Limbs(ref[0, pl.ds(g, 1), :],
+                                     ref[1, pl.ds(g, 1), :])
+        return ref[pl.ds(g, 1), :]
 
     def write_carry(g, word):
-        if ws.limbs == 2:
-            carry_ref[0, g] = word.lo
-            carry_ref[1, g] = word.hi
+        if two_limb:
+            carry_ref[0, pl.ds(g, 1), :] = word.lo
+            carry_ref[1, pl.ds(g, 1), :] = word.hi
         else:
-            carry_ref[g] = word
-
-    xb = x_ref[0]                                # [s_pad, bc] int8 unsigned
-    kap = ws.w_from_planes(kap_ref[...])         # [n_groups, bc] word domain
+            carry_ref[pl.ds(g, 1), :] = word
 
     def step(t, _):
         tau = t * n_i
-        upd = jnp.zeros((n_lanes, xb.shape[1]), jnp.int32)
+        upd = None
         for g in range(n_groups):
-            rows = jax.lax.dynamic_slice_in_dim(
-                xb, tau + g * n_k, n_i, axis=0)            # [n_i, bc]
-            iota = bseg_common.pack_iota(rows, plan, axis=0)
-            kap_g = ws.w_map(kap, lambda a: a[g])
+            iota = bseg_common.pack_iota(
+                [x_ref[0, pl.ds(tau + g * n_k + j, 1), :]
+                 for j in range(n_i)], plan)                 # [1, bc]
             # wide MAC + C port
-            word = ws.w_add(ws.w_mul(kap_g, iota), read_carry(g))
+            word = ws.w_add(ws.w_mul(row(kap_ref, g), iota),
+                            row(carry_ref, g))
             # emit completed lanes + slice carried lanes (Fig. 7)
             lanes, c_next = bseg_common.split_word(word, plan)
             write_carry(g, c_next)
-            upd = upd + jnp.stack(lanes, axis=0)
-        prev = jax.lax.dynamic_slice_in_dim(buf_ref[...], tau, n_lanes,
-                                            axis=0)
-        buf_ref[...] = jax.lax.dynamic_update_slice_in_dim(
-            buf_ref[...], prev + upd, tau, axis=0)
+            upd = lanes if upd is None else [u + l for u, l in
+                                             zip(upd, lanes)]
+        for p, lane in enumerate(upd):
+            buf_ref[pl.ds(tau + p, 1), :] += lane
         return 0
 
     jax.lax.fori_loop(0, n_steps, step, 0)
-    o_ref[0] = jax.lax.slice_in_dim(buf_ref[...], n_k - 1, n_k - 1 + s_out,
-                                    axis=0)
+    o_ref[0] = buf_ref[pl.ds(n_k - 1, s_out), :]
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "s_out", "bc",
                                              "interpret"))
 def bseg_conv1d(x_pad: jnp.ndarray, kappa: jnp.ndarray, *, plan: BSEGPlan,
-                s_out: int, bc: int = 128,
-                interpret: bool = True) -> jnp.ndarray:
+                s_out: int, interpret: bool,
+                bc: int = 128) -> jnp.ndarray:
     """Depthwise causal conv through the BSEG datapath.
 
     Args:
@@ -99,6 +100,7 @@ def bseg_conv1d(x_pad: jnp.ndarray, kappa: jnp.ndarray, *, plan: BSEGPlan,
         or 2-limb int32 for the wide DSP words — see
         ``bseg_common.WordSpec``).
       s_out: number of output samples.
+      interpret: run the Pallas interpreter (CPU) instead of Mosaic.
 
     Returns:
       [B, S_out, C] int32 — exact correlation totals (bias removed).
@@ -112,6 +114,9 @@ def bseg_conv1d(x_pad: jnp.ndarray, kappa: jnp.ndarray, *, plan: BSEGPlan,
     assert s_pad >= need, (s_pad, need)
     bc = min(bc, c)
     assert c % bc == 0
+    # staged as int32 so every per-step sample read is a 32-bit
+    # sublane row (the int8 layout packs four rows per sublane)
+    x_pad = x_pad.astype(jnp.int32)
     buf_len = n_steps * n_i + plan.n_lanes + 8
     grid = (b, c // bc)
     if ws.limbs == 2:

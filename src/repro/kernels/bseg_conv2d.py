@@ -24,16 +24,18 @@ Mapping (Figs. 6/7):
     back onto the datapath, only the extracted high parts and the
     completed low lanes are summed over (r, ci) — the paper's adder
     tree — into the VMEM row accumulator;
-  * output channels ride the VPU lane dimension (``bco`` lanes), output
-    rows the sublane dimension (``bh``): one word computation is a
-    ``[bh, kh*C_in, bco]`` elementwise multiply, i.e. every wide
-    multiplier in the emulated array is busy every step.
+  * output channels ride the VPU lane dimension (``bco`` lanes), the
+    fused pipelines the sublanes: one word computation is a
+    ``[kh*C_in, bco]`` elementwise multiply, i.e. every wide multiplier
+    in the emulated array is busy every step.
 
-Grid: (batch, H_out/bh, C_out/bco).  The activation block is the full
-padded frame (rows are re-read with a kh-1 halo via in-kernel dynamic
-slices — BlockSpec offsets are block-strided, so overlapping row blocks
-cannot be expressed in the index map); the accumulator buffer
-[bh, n_steps*n_i + n_lanes, bco] lives in VMEM scratch.
+Grid: (batch, H_out, C_out/bco) — one output row per program.  The
+wrapper materializes each output row's fused input ``[W_pad,
+kh*C_in]`` (the kh-1 row halo duplicated in HBM — BlockSpec offsets
+are block-strided, so overlapping row blocks cannot be expressed in
+the index map), so every in-kernel dynamic index is a sublane row of a
+ref; the accumulator buffer ``[n_steps*n_i + n_lanes, bco]`` lives in
+VMEM scratch.
 
 Stride 1, 'same' padding (odd kw, or kh == kw == 1); the ops wrapper
 owns padding, zero points and layout (see ``ops.packed_conv2d``).
@@ -51,65 +53,56 @@ from repro.core.datapath import BSEGPlan
 from . import bseg_common
 
 
-def _body(plan: BSEGPlan, n_groups: int, kh: int, n_steps: int,
-          w_out: int, bh: int, x_ref, kap_ref, o_ref, buf_ref):
+def _body(plan: BSEGPlan, n_groups: int, n_steps: int, w_out: int,
+          x_ref, kap_ref, o_ref, buf_ref):
+    """One output row of one output-channel block.
+
+    ``x_ref`` is the row's fused input ``[W_pad, kh*C_in]`` (the kernel
+    rows of every input channel side by side on the lane axis), so a
+    step reads its ``n_i`` samples as sublane rows of the ref; the
+    packed factor is turned into a ``[kh*C_in, 1]`` column once per
+    step so the wide word is a plain 2-D ``[kh*C_in, bco]`` array.
+    """
     n_k, n_i = plan.n_k, plan.n_i
-    n_lanes = plan.n_lanes
     ws = bseg_common.word_spec(plan)
+    khc, bco = (kap_ref.shape[-2], kap_ref.shape[-1])
 
     buf_ref[...] = jnp.zeros_like(buf_ref)
 
-    xb = x_ref[0]                          # [H_pad, W_pad, C_in] int8
-    c_in = xb.shape[2]
-    bco = o_ref.shape[3]
-    khc = kh * c_in
-    row0 = pl.program_id(1) * bh
-
-    # fuse the (kernel row, input channel) pipelines into one axis:
-    # xf[y, w, r*C_in + ci] = xb[row0 + y + r, w, ci]
-    xf = jnp.concatenate(
-        [jax.lax.dynamic_slice_in_dim(xb, row0 + r, bh, axis=0)
-         for r in range(kh)], axis=2)      # [bh, W_pad, kh*C_in]
-    kap = ws.w_map(ws.w_from_planes(kap_ref[...]),
-                   lambda a: a.reshape(n_groups, khc, bco))
-
     for g in range(n_groups):
-        kap_g = ws.w_map(kap, lambda a, g=g: a[g])     # [khc, bco]
+        if ws.limbs == 2:
+            kap_g = bseg_common.Limbs(kap_ref[0, g], kap_ref[1, g])
+        else:
+            kap_g = kap_ref[g]                             # [khc, bco]
 
         def step(t, carry, g=g, kap_g=kap_g):
             tau = t * n_i
-            seg = jax.lax.dynamic_slice_in_dim(
-                xf, tau + g * n_k, n_i, axis=1)        # [bh, n_i, khc]
-            iota = bseg_common.pack_iota(seg, plan, axis=1)  # [bh, khc]
-            word = ws.w_add(                           # [bh, khc, bco]
-                ws.w_mul(ws.w_map(kap_g, lambda a: a[None]),
-                         ws.w_map(iota, lambda a: a[..., None])),
-                carry)
+            iota = bseg_common.pack_iota(
+                [x_ref[0, 0, pl.ds(tau + g * n_k + j, 1), :]
+                 for j in range(n_i)], plan)               # [1, khc]
+            iota = ws.w_map(iota, jnp.transpose)           # [khc, 1]
+            word = ws.w_add(ws.w_mul(kap_g, iota), carry)  # [khc, bco]
             # Fig. 7 slicing per pipeline, THEN the adder tree over (r, ci)
             lanes, c_next = bseg_common.split_word(word, plan)
-            upd = jnp.stack([l.sum(axis=1, dtype=jnp.int32) for l in lanes],
-                            axis=1)                        # [bh, n_lanes, bco]
-            prev = jax.lax.dynamic_slice(
-                buf_ref[...], (0, tau, 0), (bh, n_lanes, bco))
-            buf_ref[...] = jax.lax.dynamic_update_slice(
-                buf_ref[...], prev + upd, (0, tau, 0))
+            for p, lane in enumerate(lanes):
+                buf_ref[pl.ds(tau + p, 1), :] += jnp.sum(
+                    lane, axis=0, keepdims=True, dtype=jnp.int32)
             return c_next
 
         # the carry word is a fori_loop carry: a jnp array, or a Limbs
         # pytree on the 2-limb specs
-        carry0 = ws.w_full((bh, khc, bco), ws.bias_full)
-        jax.lax.fori_loop(0, n_steps, step, carry0)
+        jax.lax.fori_loop(0, n_steps, step, ws.w_full((khc, bco),
+                                                      ws.bias_full))
 
     # buffer index = output column + n_k - 1
-    o_ref[0] = jax.lax.slice_in_dim(buf_ref[...], n_k - 1, n_k - 1 + w_out,
-                                    axis=1)
+    o_ref[0, 0] = buf_ref[pl.ds(n_k - 1, w_out), :]
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "h_out", "w_out",
-                                             "bh", "bco", "interpret"))
+                                             "bco", "interpret"))
 def bseg_conv2d(x_pad: jnp.ndarray, kappa: jnp.ndarray, *, plan: BSEGPlan,
-                h_out: int, w_out: int, bh: int = 8, bco: int = 128,
-                interpret: bool = True) -> jnp.ndarray:
+                h_out: int, w_out: int, interpret: bool,
+                bco: int = 128) -> jnp.ndarray:
     """Dense stride-1 conv2d through the BSEG datapath.
 
     Args:
@@ -126,8 +119,9 @@ def bseg_conv2d(x_pad: jnp.ndarray, kappa: jnp.ndarray, *, plan: BSEGPlan,
         or 2-limb int32 for the wide DSP words — see
         ``bseg_common.WordSpec``).
       h_out / w_out: output frame size.
-      bh / bco: output-row / output-channel block sizes (must divide
-        h_out / C_out; the ops wrapper downgrades them if not).
+      interpret: run the Pallas interpreter (CPU) instead of Mosaic.
+      bco: output-channel block size (must divide C_out; the ops
+        wrapper downgrades it if not).
 
     Returns:
       [B, h_out, w_out, C_out] int32 — exact correlation totals summed
@@ -147,33 +141,40 @@ def bseg_conv2d(x_pad: jnp.ndarray, kappa: jnp.ndarray, *, plan: BSEGPlan,
     n_steps = -(-(w_out + n_k - 1) // n_i)
     need = (n_steps - 1) * n_i + (n_groups - 1) * n_k + n_i
     assert w_pad >= need, (w_pad, need)
-    bh = min(bh, h_out)
     bco = min(bco, c_out)
-    assert h_out % bh == 0 and c_out % bco == 0, (h_out, bh, c_out, bco)
+    assert c_out % bco == 0, (c_out, bco)
+    khc = kh * c_in
     buf_len = n_steps * n_i + plan.n_lanes + 8
-    grid = (b, h_out // bh, c_out // bco)
+    # fuse the (kernel row, input channel) pipelines into one lane axis:
+    # xf[b, y, w, r*C_in + ci] = x_pad[b, y + r, w, ci] — the kh-row
+    # halo is materialized here (kh x the small int8 frame, staged as
+    # int32 rows), so each grid step's block is one plain output row
+    xf = jnp.concatenate([x_pad[:, r:r + h_out] for r in range(kh)],
+                         axis=-1).astype(jnp.int32)   # [B, h_out, W_pad, khc]
+    kap = kappa.reshape(kappa.shape[:-3] + (khc, c_out))
+    grid = (b, h_out, c_out // bco)
     if ws.limbs == 2:
-        kap_spec = pl.BlockSpec((2, n_groups, kh, c_in, bco),
-                                lambda ib, ih, ic: (0, 0, 0, 0, ic))
-    else:
-        kap_spec = pl.BlockSpec((n_groups, kh, c_in, bco),
+        kap_spec = pl.BlockSpec((2, n_groups, khc, bco),
                                 lambda ib, ih, ic: (0, 0, 0, ic))
+    else:
+        kap_spec = pl.BlockSpec((n_groups, khc, bco),
+                                lambda ib, ih, ic: (0, 0, ic))
     return pl.pallas_call(
-        functools.partial(_body, plan, n_groups, kh, n_steps, w_out, bh),
+        functools.partial(_body, plan, n_groups, n_steps, w_out),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, h_pad, w_pad, c_in),
-                         lambda ib, ih, ic: (ib, 0, 0, 0)),
+            pl.BlockSpec((1, 1, w_pad, khc),
+                         lambda ib, ih, ic: (ib, ih, 0, 0)),
             kap_spec,
         ],
-        out_specs=pl.BlockSpec((1, bh, w_out, bco),
+        out_specs=pl.BlockSpec((1, 1, w_out, bco),
                                lambda ib, ih, ic: (ib, ih, 0, ic)),
         out_shape=jax.ShapeDtypeStruct((b, h_out, w_out, c_out), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM((bh, buf_len, bco), jnp.int32),
+            pltpu.VMEM((buf_len, bco), jnp.int32),
         ],
         interpret=interpret,
-    )(x_pad, kappa)
+    )(xf, kap)
 
 
 def bseg_conv2d_num_multiplies(h_out: int, w_out: int, c_in: int,

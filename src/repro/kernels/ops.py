@@ -2,9 +2,12 @@
 
 Each op handles layout preparation (weight packing, padding, transposes,
 zero points, dequantization scales) and exposes a ``use_kernel`` switch:
-``True`` runs the Pallas kernel (interpret mode on CPU, compiled on
-TPU), ``False`` runs an equivalent pure-jnp path — the form the model
-layer lowers in the multi-pod dry-run, where XLA owns the fusion.
+``True`` runs the Pallas kernel — the Pallas interpreter on CPU (the
+tests), Mosaic-compiled on a TPU (``tests/test_tpu_compile.py``
+compiles the main-path kernels for a v5e at real widths;
+``chip_smoke.py`` runs them on the chip) — ``False`` runs an
+equivalent pure-jnp path, the form the model layer lowers in the
+multi-pod dry-run, where XLA owns the fusion.
 
 Dispatch table for ``packed_matmul`` (mode -> kernel -> constraints):
 
@@ -45,7 +48,7 @@ Dispatch table for ``packed_conv2d`` (mode -> kernel -> constraints):
   -------------  --------------------------  ------------------------------
   bseg_conv2d    kernels/bseg_conv2d         integer x; BSEG ``plan`` on
                  (cross-channel batched      any datapath — the kernel
-                 conv2d, grid B x H/bh x     body is word-generic (1-limb
+                 conv2d, grid B x H x        body is word-generic (1-limb
                  C_out/bco, fused (kh,C_in)  int32 / fp32, or 2-limb int32
                  pipeline axis, VMEM row     for the wide DSP48E2/DSP58
                  accumulator)                words, per
@@ -80,7 +83,6 @@ need the wider storage layout).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -127,10 +129,16 @@ def unpack_weights(packed: jnp.ndarray, *, w: int,
 
 def quant_matmul(x: jnp.ndarray, w_packed: jnp.ndarray, scale: jnp.ndarray,
                  *, w: int, use_kernel: bool = True,
-                 block_m: int = 128, block_n: int = 256,
+                 block_m: int = 128, block_n: int = 1024,
                  block_k: int = 512) -> jnp.ndarray:
-    """x [m, k] @ dequant(w_packed [k, n/(32/w)]) -> [m, n] f32."""
+    """x [m, k] @ dequant(w_packed [k, n/(32/w)]) -> [m, n] f32.
+
+    A block that does not divide ``n`` (or ``k``) widens to the whole
+    axis, which is always a legal TPU block."""
     if use_kernel:
+        n, k = w_packed.shape[1] * (32 // w), x.shape[-1]
+        block_n = block_n if n % min(block_n, n) == 0 else n
+        block_k = block_k if k % min(block_k, k) == 0 else k
         return qmm_kernel.quant_matmul(
             x, w_packed, scale, w=w, bm=block_m, bn=block_n, bk=block_k,
             interpret=_on_cpu())
@@ -356,8 +364,7 @@ def packed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
             raise ValueError(f"route {route!r} needs scale and w_bits")
         y = quant_matmul(x2, w, scale, w=w_bits,
                          use_kernel=(route == "quant_matmul"),
-                         block_m=block_rows, block_n=block_g,
-                         block_k=block_k)
+                         block_m=block_rows, block_k=block_k)
         y = y if m is None else y[:, :m]
         return y.reshape(batch_shape + y.shape[-1:])
 
@@ -665,8 +672,7 @@ def _im2col_patches(x32: jnp.ndarray, kh: int, kw: int) -> jnp.ndarray:
 
 def packed_conv2d(x: jnp.ndarray, w_int: jnp.ndarray, *, plan: BSEGPlan,
                   mode: str = "auto", zero_point: int = 0,
-                  use_kernel: bool = True, block_h: int = 8,
-                  block_co: int = 128,
+                  use_kernel: bool = True, block_co: int = 128,
                   sdv_plan: Optional[SDVPlan] = None) -> jnp.ndarray:
     """Stride-1 'same'-pad conv2d with kernel dispatch.
 
@@ -679,8 +685,8 @@ def packed_conv2d(x: jnp.ndarray, w_int: jnp.ndarray, *, plan: BSEGPlan,
         word in its native representation — int32 / fp32 / two int32
         limb planes for the wide DSP words).
       mode: a row of the dispatch table, or ``"auto"``.
-      block_h / block_co: output-row / output-channel block sizes for
-        the conv2d kernel (downgraded to H / C_out when not divisible).
+      block_co: output-channel block size for the conv2d kernel
+        (downgraded to C_out when not divisible).
       sdv_plan: optional SDV plan for the im2col route (the planner
         picks one per layer); defaults to the plan derived from the
         BSEG widths.  An unsigned-element-domain override
@@ -741,15 +747,11 @@ def packed_conv2d(x: jnp.ndarray, w_int: jnp.ndarray, *, plan: BSEGPlan,
         xu, ((0, 0), (pad_h, pad_h),
              (pad_w, max(pad_w, need - (w + pad_w))), (0, 0)),
         constant_values=zero_point)
-    bh = min(block_h, h)
-    if h % bh:
-        bh = h
     bco = min(block_co, c_out)
     if c_out % bco:
         bco = c_out
     y = bseg2d_kernel.bseg_conv2d(x_pad, kappa, plan=plan, h_out=h,
-                                  w_out=w, bh=bh, bco=bco,
-                                  interpret=_on_cpu())
+                                  w_out=w, bco=bco, interpret=_on_cpu())
     if zero_point:
         y = y - zero_point * tap_sum[None, None, None, :]
     return y

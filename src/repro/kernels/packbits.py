@@ -38,8 +38,8 @@ def _pack_body(w: int, vals_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("w", "block", "interpret"))
-def unpack_words(packed: jnp.ndarray, *, w: int, block: int = 256,
-                 interpret: bool = True) -> jnp.ndarray:
+def unpack_words(packed: jnp.ndarray, *, w: int, interpret: bool,
+                 block: int = 256) -> jnp.ndarray:
     """int32 [m, n_words] -> int8 [m, n_words * (32//w)] (sign-extended)."""
     m, nw = packed.shape
     per = 32 // w
@@ -57,8 +57,8 @@ def unpack_words(packed: jnp.ndarray, *, w: int, block: int = 256,
 
 
 @functools.partial(jax.jit, static_argnames=("w", "block", "interpret"))
-def pack_words(vals: jnp.ndarray, *, w: int, block: int = 256,
-               interpret: bool = True) -> jnp.ndarray:
+def pack_words(vals: jnp.ndarray, *, w: int, interpret: bool,
+               block: int = 256) -> jnp.ndarray:
     """int8 [m, n] -> int32 [m, n // (32//w)] lane words."""
     m, n = vals.shape
     per = 32 // w

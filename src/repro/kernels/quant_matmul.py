@@ -5,7 +5,7 @@ paper's packing applied to the *memory* side of the TPU roofline) and
 are expanded to the compute dtype inside VMEM, right before the MXU dot.
 HBM traffic for the weight operand drops by 16/w vs bf16 — on the
 memory-bound decode shapes this moves the dominant roofline term by the
-same factor (EXPERIMENTS.md §Perf).
+same factor.
 
 Blocking: grid (m/bm, n/bn, k/bk), k innermost; fp32 accumulation in a
 VMEM scratch tile; per-output-channel scales fused on the final k step.
@@ -30,52 +30,55 @@ def _body(w: int, nsteps_k: int, x_ref, wp_ref, scale_ref, o_ref, acc_ref):
 
     per = 32 // w
     words = wp_ref[...]                                    # [bk, bn/per] i32
-    bk = words.shape[0]
-    cols = []
+    x = x_ref[...].astype(jnp.float32)                     # [bm, bk]
+    # field i of word j is output column j*per + i: one MXU dot per
+    # field, each into its own accumulator plane (the column interleave
+    # happens outside the kernel, on the small output)
     for i in range(per):
         f = (words >> (i * w)) & ((1 << w) - 1)
         f = jnp.where(f >= (1 << (w - 1)), f - (1 << w), f)
-        cols.append(f)
-    # word j holds columns j*per .. j*per+per-1 (minor-axis interleave)
-    wb = jnp.stack(cols, axis=-1).reshape(bk, -1)          # [bk, bn] int
-    x = x_ref[...]                                         # [bm, bk]
-    acc_ref[...] += jax.lax.dot_general(
-        x.astype(jnp.float32), wb.astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        acc_ref[i] += jax.lax.dot_general(
+            x, f.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(k_step == nsteps_k - 1)
     def _flush():
-        o_ref[...] = acc_ref[...] * scale_ref[...].astype(jnp.float32)
+        for i in range(per):
+            o_ref[i] = acc_ref[i] * scale_ref[pl.ds(i, 1), :]
 
 
 @functools.partial(jax.jit, static_argnames=("w", "bm", "bn", "bk",
                                              "interpret"))
 def quant_matmul(x: jnp.ndarray, w_packed: jnp.ndarray, scale: jnp.ndarray,
-                 *, w: int, bm: int = 128, bn: int = 256, bk: int = 512,
-                 interpret: bool = True) -> jnp.ndarray:
+                 *, w: int, interpret: bool, bm: int = 128, bn: int = 1024,
+                 bk: int = 512) -> jnp.ndarray:
     """x [m, k] (bf16/f32)  @  packed weights [k, n/(32/w)] int32 -> [m, n].
 
-    ``scale`` is the per-output-channel dequantization scale [n].
+    ``scale`` is the per-output-channel dequantization scale [n].  The
+    weight block is ``[bk, bn/(32/w)]`` words; the default ``bn`` keeps
+    that lane width a multiple of 128 at w=4.
     """
     m, k = x.shape
     per = 32 // w
-    n = w_packed.shape[1] * per
+    nw = w_packed.shape[1]
+    n = nw * per
     bm = min(bm, m)
     bn = min(bn, n)
     bk = min(bk, k)
     assert n % bn == 0 and k % bk == 0 and bn % per == 0, (m, n, k, bm, bn, bk)
+    bw = bn // per
     grid = (pl.cdiv(m, bm), n // bn, k // bk)
-    return pl.pallas_call(
+    planes = pl.pallas_call(
         functools.partial(_body, w, k // bk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn // per), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((bk, bw), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((per, bw), lambda i, j, kk: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        out_specs=pl.BlockSpec((per, bm, bw), lambda i, j, kk: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((per, m, nw), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((per, bm, bw), jnp.float32)],
         interpret=interpret,
-    )(x, w_packed, scale.reshape(1, n))
+    )(x, w_packed, scale.reshape(nw, per).T)
+    return jnp.transpose(planes, (1, 2, 0)).reshape(m, n)

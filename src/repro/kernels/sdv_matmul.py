@@ -24,8 +24,9 @@ Same on-chip architecture as the GEMV kernel:
 Grid: (R/br, G/bg, K/bk) with K innermost; the accumulator word and
 the spill totals live in VMEM scratch across K steps.  Rows are
 blocked at GEMM granularity (default 128) instead of the GEMV
-kernel's 8, and the activation block is row-major ``[br, bk]`` — no
-caller-side transpose.
+kernel's 8.  Both wrappers hand the kernel a K-major ``[bk, br]``
+activation block, so each K step reads one sublane row of each
+operand ref.
 
 The body is *word-generic* (``bseg_common.sdv_word_spec``): one int32
 limb for plans whose storage layout fits the 32-bit TPU lane, two
@@ -67,18 +68,22 @@ def _lsb2(d_word, sign_bits, i: int, lane: int, w_a: int, signed_a: bool):
 
 
 def _body(plan_n: int, lane: int, w_a: int, signed_a: bool, signed: bool,
-          sign_shift: int, nsteps_k: int, bk: int, x_k_axis: int,
+          sign_shift: int, nsteps_k: int, bk: int,
           ws: bseg_common.WordSpec,
           x_ref, w_ref, o_ref, word_ref, spill_ref):
     """Shared GEMM/GEMV kernel body.
 
-    ``x_k_axis`` selects the activation block layout: 1 for the GEMM's
-    row-major ``[rows, bk]`` block, 0 for the GEMV's K-major
-    ``[bk, rows]`` block (``kernels/sdv_matvec`` reuses this body).
-    ``ws`` is the storage-word representation
+    The activation block is K-major ``[bk, rows]`` and the storage
+    block ``[bk, bg]`` (leading ``(2,)`` limb-plane axis on 2-limb
+    specs), so step ``j`` of the K loop reads row ``j`` of each ref —
+    a dynamic *sublane* offset, which Mosaic lowers — and never slices
+    a loaded value.  ``ws`` is the storage-word representation
     (``bseg_common.sdv_word_spec``): one int32 limb, or two int32 limb
-    planes for the wide DSP48E2/DSP58 words (leading (2,) axis on the
-    storage operand and the accumulator scratch).
+    planes for the wide DSP48E2/DSP58 words.  The accumulator word is a
+    ``[rows, bg]`` scratch (limb planes on 2-limb specs) and the spill
+    totals an ``[n, rows, bg]`` scratch; the output block is
+    ``[n, rows, bg]`` — lane index leading, so no array ever carries
+    the tiny lane count on the 128-wide vector lane axis.
     """
     k_step = pl.program_id(2)
     n = plan_n
@@ -89,19 +94,15 @@ def _body(plan_n: int, lane: int, w_a: int, signed_a: bool, signed: bool,
         word_ref[...] = jnp.zeros_like(word_ref)
         spill_ref[...] = jnp.zeros_like(spill_ref)
 
-    # [rows, bk] or [bk, rows]; limb MACs lift int32 on the fly
-    xb = x_ref[...].astype(jnp.int32 if two_limb else ws.dtype)
-    wbw = ws.w_from_planes(w_ref[...])    # [bk, bg] storage words
-
-    def mask32(x, bits):
-        return x & ((1 << bits) - 1)
+    def read_stored(j):
+        if two_limb:
+            return Limbs(w_ref[0, pl.ds(j, 1), :], w_ref[1, pl.ds(j, 1), :])
+        return w_ref[pl.ds(j, 1), :]                                 # [1, bg]
 
     def step(j, carry):
         word, spills = carry
-        xk = jax.lax.dynamic_index_in_dim(xb, j, x_k_axis,
-                                          keepdims=False)             # [rows]
-        stored = ws.w_map(wbw, lambda a: jax.lax.dynamic_index_in_dim(
-            a, j, 0, keepdims=False))
+        xk = jnp.transpose(x_ref[pl.ds(j, 1), :].astype(jnp.int32))  # [rows,1]
+        stored = read_stored(j)
         d_word = ws.mod_pow2(stored, sign_shift)
         if signed_a:
             if two_limb:
@@ -116,45 +117,42 @@ def _body(plan_n: int, lane: int, w_a: int, signed_a: bool, signed: bool,
                     a_word,
                     ws.w_shift_left(ws.w_from_i32(bit, signed=False),
                                     i * lane + w_a - 1))
-            packed = ws.w_sub(d_word, a_word)                         # [bg]
+            packed = ws.w_sub(d_word, a_word)                         # [1,bg]
         else:
             sign_bits = jnp.zeros_like(ws.w_lo_i32(d_word))
             packed = d_word               # unsigned: plain concatenation
         # ---- wide MAC --------------------------------------------------
-        word2 = ws.w_add(word, ws.w_mul(
-            ws.w_map(packed, lambda a: a[None, :]),
-            ws.w_from_i32(xk[:, None]) if two_limb else xk[:, None])) # [br,bg]
+        word2 = ws.w_add(word, ws.w_mul(packed, ws.w_from_i32(xk)))  # [br,bg]
         # ---- mod-4 spill tracking (fractured-LUT reference) ------------
-        x4 = (xk & 3)[:, None]                                        # [br,1]
+        x4 = xk & 3                                                   # [br,1]
         new_spills = []
         for i in range(1, n + 1):
             prev = ws.w_lo_i32(ws.field(word, i * lane, 2))
             obs = ws.w_lo_i32(ws.field(word2, i * lane, 2))
             if i < n:
                 p4 = (_lsb2(d_word, sign_bits, i, lane, w_a,
-                            signed_a)[None, :] * x4) & 3
+                            signed_a) * x4) & 3
             else:
                 p4 = 0                    # virtual observer lane
             mm = (obs - prev - p4) & 3
             # signed products spill [-1, 1]; unsigned spill [0, 2]
             delta = jnp.where(mm == 3, -1, mm) if signed else mm
-            new_spills.append(spills[..., i - 1]
-                              + delta.astype(jnp.int32))
-        spills = jnp.stack(new_spills, axis=-1)                       # [br,bg,n]
-        return word2, spills
+            new_spills.append(spills[i - 1] + delta.astype(jnp.int32))
+        return word2, tuple(new_spills)
 
     word, spills = jax.lax.fori_loop(
-        0, bk, step, (ws.w_from_planes(word_ref[...]), spill_ref[...]))
+        0, bk, step, (ws.w_from_planes(word_ref[...]),
+                      tuple(spill_ref[i] for i in range(n))))
     word_ref[...] = ws.w_to_planes(word)
-    spill_ref[...] = spills
+    for i in range(n):
+        spill_ref[i] = spills[i]
 
     @pl.when(k_step == nsteps_k - 1)
     def _extract():
         # Eq. 3:  R̂_i = (2^L S_i + R_i) - S_{i-1}
-        outs = []
         for i in range(n):
             field = ws.field(word, i * lane, lane)
-            s_i = spills[..., i]
+            s_i = spills[i]
             # lane results are exact dot products that fit int32 on
             # every plan; the wide-word path computes them mod 2^64 in
             # the limb domain and hands back the low limb — the same
@@ -163,41 +161,26 @@ def _body(plan_n: int, lane: int, w_a: int, signed_a: bool, signed: bool,
                 acc = limb_ops.add(limb_ops.shift_left(
                     limb_ops.from_i32(s_i), lane), field)
                 if i > 0:
-                    acc = limb_ops.sub(
-                        acc, limb_ops.from_i32(spills[..., i - 1]))
-                outs.append(acc.lo)
+                    acc = limb_ops.sub(acc, limb_ops.from_i32(spills[i - 1]))
+                o_ref[i] = acc.lo
             else:
-                s_prev = spills[..., i - 1] if i > 0 else 0
-                outs.append(((s_i.astype(ws.dtype) << lane)
-                             + field - s_prev).astype(jnp.int32))
-        o_ref[...] = jnp.stack(outs, axis=-1)                         # [br,bg,n]
+                s_prev = spills[i - 1] if i > 0 else 0
+                o_ref[i] = ((s_i.astype(ws.dtype) << lane)
+                            + field - s_prev).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "br", "bg", "bk",
-                                             "interpret"))
-def sdv_matmul(x_q: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
-               br: int = 128, bg: int = 128, bk: int = 512,
-               interpret: bool = True) -> jnp.ndarray:
-    """Packed GEMM.
+def sdv_call(x_t: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
+             br: int, bg: int, bk: int, interpret: bool) -> jnp.ndarray:
+    """The SDV pallas_call shared by the GEMM and GEMV wrappers.
 
-    Args:
-      x_q: [R, K] integer activations (row-major), values within w_b
-        bits (signed or unsigned per ``plan.signed_b``).
-      w_words: [K, G] storage words (``prepare_sdv_weights``) in the
-        plan's transport layout — int32, with a leading (2,) limb-plane
-        axis ([2, K, G]) for wide (DSP48E2/DSP58) words.
-      plan: SDV lane plan on any exact-wrap datapath.
-
-    Returns:
-      [R, G, n] int32 — exact per-lane dot products (dequantize
-      outside).  K must be a multiple of ``bk`` (zero-pad K outside:
-      zero activations produce zero products and zero spills, so the
-      padding is exact).
+    ``x_t`` is the K-major ``[K, R]`` activation; returns ``[R, G, n]``
+    int32 exact per-lane dot products.  Grid ``(R/br, G/bg, K/bk)``
+    with K innermost; ragged R/G edge blocks only feed padding lanes
+    and rows, which the caller trims.
     """
-    r, k = x_q.shape
+    k, r = x_t.shape
     g = w_words.shape[-1]
     n, lane = plan.n, plan.lane
-    sign_shift = plan.packed_width
     ws = bseg_common.sdv_word_spec(plan)
     assert ws.exact_wrap, plan.spec.name     # spill tracking needs wrap
     assert bseg_common.sdv_layout_bits(plan) <= plan.spec.w_word, plan
@@ -214,22 +197,51 @@ def sdv_matmul(x_q: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
         w_spec = pl.BlockSpec((2, bk, bg), lambda ir, ig, ik: (0, ik, ig))
     else:
         w_spec = pl.BlockSpec((bk, bg), lambda ir, ig, ik: (ik, ig))
-    return pl.pallas_call(
+    lanes = pl.pallas_call(
         functools.partial(_body, n, lane, plan.w_a, plan.signed_a, signed,
-                          sign_shift, k // bk, bk, 1, ws),
+                          plan.packed_width, k // bk, bk, ws),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((br, bk), lambda ir, ig, ik: (ir, ik)),
+            pl.BlockSpec((bk, br), lambda ir, ig, ik: (ik, ir)),
             w_spec,
         ],
-        out_specs=pl.BlockSpec((br, bg, n), lambda ir, ig, ik: (ir, ig, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, g, n), jnp.int32),
+        out_specs=pl.BlockSpec((n, br, bg), lambda ir, ig, ik: (0, ir, ig)),
+        out_shape=jax.ShapeDtypeStruct((n, r, g), jnp.int32),
         scratch_shapes=[
             pltpu.VMEM(ws.plane_shape((br, bg)), ws.dtype),
-            pltpu.VMEM((br, bg, n), jnp.int32),
+            pltpu.VMEM((n, br, bg), jnp.int32),
         ],
         interpret=interpret,
-    )(x_q, w_words)
+    )(x_t, w_words)
+    return jnp.transpose(lanes, (1, 2, 0))                        # [R, G, n]
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "br", "bg", "bk",
+                                             "interpret"))
+def sdv_matmul(x_q: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
+               interpret: bool, br: int = 128, bg: int = 128,
+               bk: int = 512) -> jnp.ndarray:
+    """Packed GEMM.
+
+    Args:
+      x_q: [R, K] integer activations (row-major), values within w_b
+        bits (signed or unsigned per ``plan.signed_b``).
+      w_words: [K, G] storage words (``prepare_sdv_weights``) in the
+        plan's transport layout — int32, with a leading (2,) limb-plane
+        axis ([2, K, G]) for wide (DSP48E2/DSP58) words.
+      plan: SDV lane plan on any exact-wrap datapath.
+      interpret: run the Pallas interpreter (CPU) instead of Mosaic.
+
+    Returns:
+      [R, G, n] int32 — exact per-lane dot products (dequantize
+      outside).  K must be a multiple of ``bk`` (zero-pad K outside:
+      zero activations produce zero products and zero spills, so the
+      padding is exact).  The activations are handed to the kernel
+      K-major (one XLA transpose of the small activation operand), so
+      the GEMM and the GEMV share one body and one layout.
+    """
+    return sdv_call(jnp.transpose(x_q), w_words, plan=plan, br=br, bg=bg,
+                    bk=bk, interpret=interpret)
 
 
 def sdv_num_multiplies(rows: int, m: int, k: int, plan: SDVPlan) -> int:

@@ -19,10 +19,10 @@ Grid: (B/bb, G/bg, K/bk) with K innermost; the accumulator word and the
 spill totals live in VMEM scratch across K steps.  Layouts are K-major
 so the per-step slice is a sublane read.
 
-The kernel body (pre-adder, spill tracker, extractor) is shared with
-the batched GEMM kernel — ``kernels/sdv_matmul._body`` with the
-K-major activation layout (``x_k_axis=0``); this wrapper is the
-decode-micro-batch special case.  Like the GEMM kernel the body is
+The kernel (pre-adder, spill tracker, extractor) is shared with the
+batched GEMM — ``kernels/sdv_matmul.sdv_call``, which takes the
+K-major activation layout this wrapper is given; this wrapper is the
+decode-micro-batch special case (8-row blocks).  The body is
 word-generic (``bseg_common.sdv_word_spec``): one int32 limb, or two
 carry-propagating int32 limb planes for the wide DSP48E2/DSP58 words
 — every plan compiles on any backend with int32.
@@ -33,19 +33,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.datapath import SDVPlan
-from . import bseg_common
-from .sdv_matmul import _body
+from .sdv_matmul import sdv_call
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "bb", "bg", "bk",
                                              "interpret"))
 def sdv_matvec(x_t: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
-               bb: int = 8, bg: int = 128, bk: int = 512,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool, bb: int = 8, bg: int = 128,
+               bk: int = 512) -> jnp.ndarray:
     """Packed GEMV.
 
     Args:
@@ -54,43 +51,10 @@ def sdv_matvec(x_t: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
         the plan's transport layout (leading (2,) limb-plane axis for
         wide words: [2, K, G]).
       plan: SDV lane plan on any exact-wrap datapath.
+      interpret: run the Pallas interpreter (CPU) instead of Mosaic.
 
     Returns:
       [B, G, n] int32 — exact per-lane dot products (dequantize outside).
     """
-    k, b = x_t.shape
-    g = w_words.shape[-1]
-    n, lane = plan.n, plan.lane
-    sign_shift = plan.packed_width
-    ws = bseg_common.sdv_word_spec(plan)
-    assert ws.exact_wrap, plan.spec.name     # spill tracking needs wrap
-    assert bseg_common.sdv_layout_bits(plan) <= plan.spec.w_word, plan
-    assert w_words.dtype == ws.dtype, (w_words.dtype, ws.dtype)
-    assert w_words.ndim == (3 if ws.limbs == 2 else 2), \
-        (w_words.shape, ws.limbs)
-    bb = min(bb, b)
-    bg = min(bg, g)
-    bk = min(bk, k)
-    assert k % bk == 0, (k, bk)
-    signed = plan.signed_a or plan.signed_b
-    grid = (pl.cdiv(b, bb), pl.cdiv(g, bg), k // bk)
-    if ws.limbs == 2:
-        w_spec = pl.BlockSpec((2, bk, bg), lambda ib, ig, ik: (0, ik, ig))
-    else:
-        w_spec = pl.BlockSpec((bk, bg), lambda ib, ig, ik: (ik, ig))
-    return pl.pallas_call(
-        functools.partial(_body, n, lane, plan.w_a, plan.signed_a, signed,
-                          sign_shift, k // bk, bk, 0, ws),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bk, bb), lambda ib, ig, ik: (ik, ib)),
-            w_spec,
-        ],
-        out_specs=pl.BlockSpec((bb, bg, n), lambda ib, ig, ik: (ib, ig, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, g, n), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM(ws.plane_shape((bb, bg)), ws.dtype),
-            pltpu.VMEM((bb, bg, n), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x_t, w_words)
+    return sdv_call(x_t, w_words, plan=plan, br=bb, bg=bg, bk=bk,
+                    interpret=interpret)

@@ -37,18 +37,6 @@ DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
                "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    jax 0.4.x returns a one-element *list* of dicts (one per program);
-    newer jax returns the dict directly.  Always returns a dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def collective_bytes(hlo_text: str) -> dict:
     """Sum per-device collective operand bytes from optimized HLO."""
     out = {}
@@ -172,7 +160,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             compiled = lowered.compile()
     t1 = time.time()
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     n_dev = mesh.size

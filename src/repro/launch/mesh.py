@@ -9,15 +9,25 @@ from __future__ import annotations
 from typing import Dict
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
 
 from repro.models.param import Rules
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis in automatic-sharding mode —
+    the GSPMD propagation these programs are written for (jax's own
+    default builds explicit-sharding axes, which demand an
+    ``out_sharding`` on every gather)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def rules_for_mesh(mesh, *, fsdp: bool = False) -> Rules:

@@ -183,6 +183,12 @@ def _run_engine(cfg, args, params):
                   f"strictly denser")
     if comps:
         print("sample:", list(comps[0].tokens)[:12])
+    failed = [o["detail"] for o in engine.outcomes.values()
+              if o["outcome"] == "failed"]
+    if failed and not args.chaos:
+        # outside a chaos run a failed request means a broken device
+        # path (the engine degrades instead of dying): exit non-zero
+        raise SystemExit(f"{len(failed)} requests failed: {failed[0]}")
 
 
 def main():
@@ -255,4 +261,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

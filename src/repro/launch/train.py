@@ -23,7 +23,6 @@ from __future__ import annotations
 import argparse
 
 import jax
-import numpy as np
 
 
 def run_qat_main(args) -> None:
@@ -64,6 +63,39 @@ def run_qat_main(args) -> None:
         print(f"exported serving params -> {args.export}")
 
 
+def setup_training(cfg, mesh, *, steps: int, seq: int, global_batch: int,
+                   seed: int = 0):
+    """The float launcher's state on ``mesh``: parameters placed by the
+    sharding rules (FSDP over ``data`` when ``cfg.fsdp``), AdamW state
+    built from them, the deterministic synthetic data stream and the
+    function that shards each host batch along the batch axis.
+
+    Returns ``(rules, ocfg, params, opt, data, place_batch)``."""
+    from repro.data import SyntheticLMData
+    from repro.models import init_params, specs, values
+    from repro.train import optimizer
+    from repro.launch.mesh import (batch_shardings, rules_for_mesh,
+                                   shardings_of)
+
+    rules = rules_for_mesh(mesh, fsdp=cfg.fsdp)
+    pt = init_params(cfg, rules, jax.random.PRNGKey(seed))
+    pv, ps = values(pt), specs(pt)
+    pv = jax.device_put(pv, shardings_of(mesh, ps))
+    ocfg = optimizer.OptConfig(lr=3e-4, warmup=10, total_steps=steps,
+                               moments_8bit=cfg.opt_8bit)
+    opt = optimizer.init(ocfg, pv)
+    data = SyntheticLMData(
+        vocab=cfg.vocab, seq_len=seq, global_batch=global_batch,
+        seed=seed, n_patches=cfg.n_patches, d_model=cfg.d_model,
+        encdec=cfg.family == "encdec")
+
+    def place_batch(host):
+        shards = batch_shardings(mesh, rules, host)
+        return {k: jax.device_put(v, shards[k]) for k, v in host.items()}
+
+    return rules, ocfg, pv, opt, data, place_batch
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
@@ -100,11 +132,9 @@ def main():
         return
 
     from repro.configs.registry import get_arch
-    from repro.data import SyntheticLMData
-    from repro.models import init_params, values, specs, shard_ctx
-    from repro.train import checkpoint, loop, optimizer, straggler
-    from repro.launch.mesh import (batch_shardings, rules_for_mesh,
-                                   shardings_of)
+    from repro.models import shard_ctx
+    from repro.train import checkpoint, loop
+    from repro.launch.mesh import make_mesh
 
     cfg = get_arch(args.arch)
     if args.smoke:
@@ -114,20 +144,11 @@ def main():
         dd, mm = (int(x) for x in args.mesh.split(","))
     else:
         dd, mm = nd, 1
-    mesh = jax.make_mesh((dd, mm), ("data", "model"))
-    rules = rules_for_mesh(mesh, fsdp=cfg.fsdp)
+    mesh = make_mesh((dd, mm), ("data", "model"))
     print(f"mesh {dict(mesh.shape)}  arch {cfg.name}")
-
-    pt = init_params(cfg, rules, jax.random.PRNGKey(0))
-    pv, ps = values(pt), specs(pt)
-    pv = jax.device_put(pv, shardings_of(mesh, ps))
-    ocfg = optimizer.OptConfig(lr=3e-4, warmup=10, total_steps=args.steps,
-                               moments_8bit=cfg.opt_8bit)
-    opt = optimizer.init(ocfg, pv)
-    data = SyntheticLMData(
-        vocab=cfg.vocab, seq_len=args.seq, global_batch=args.global_batch,
-        seed=0, n_patches=cfg.n_patches, d_model=cfg.d_model,
-        encdec=cfg.family == "encdec")
+    rules, ocfg, pv, opt, data, place_batch = setup_training(
+        cfg, mesh, steps=args.steps, seq=args.seq,
+        global_batch=args.global_batch)
 
     start = 0
     if args.resume:
@@ -143,10 +164,6 @@ def main():
     checkpoint.install_sigterm_handler(
         lambda: (ck.wait(), checkpoint.save(
             args.ckpt_dir, state["step"], (state["pv"], state["opt"]))))
-
-    def place_batch(host):
-        shards = batch_shardings(mesh, rules, host)
-        return {k: jax.device_put(v, shards[k]) for k, v in host.items()}
 
     def on_step(s, p, o, m, dt, mon):
         state.update(pv=p, opt=o, step=s + 1)
@@ -168,4 +185,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -304,8 +304,7 @@ def attention_apply(params, cfg: AttnConfig, x, *, positions,
                                     "tp" if g % tp == 0 else None, None)
         else:
             # heads don't divide the model axis: leave placement to
-            # GSPMD (context-parallel q was measured WORSE — see
-            # EXPERIMENTS.md §Perf iteration log)
+            # GSPMD
             pass
     qg = q.reshape(b, s, g, r, hd)
     attend = _stream_attend_diff if differentiable else _stream_attend

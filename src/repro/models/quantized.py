@@ -27,6 +27,7 @@ See DESIGN.md §2 for when each mode wins.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -60,15 +61,22 @@ class SDVLinear:
     leading layer axis on ``words``/``scale`` ([L, d_in, G] /
     [L, 2, d_in, G] / [L, d_out]); ``lax.scan`` slices it back off,
     yielding the per-layer container unchanged (same pattern as
-    ``BSEGConv``)."""
+    ``BSEGConv``).
+
+    ``use_kernel`` pins the route of ``sdv_matmul_apply``: ``None``
+    follows the backend (Pallas on TPU, the jnp ref decode on CPU);
+    ``True``/``False`` pin the kernel/ref route on any backend — the
+    on-chip kernel-vs-ref comparison sets ``False`` on a copy of the
+    tree, which shares every array."""
     words: jnp.ndarray
     scale: jnp.ndarray
     plan: SDVPlan
     d_out: int
+    use_kernel: Optional[bool] = None
 
 
 jax.tree_util.register_dataclass(SDVLinear, data_fields=["words", "scale"],
-                                 meta_fields=["plan", "d_out"])
+                                 meta_fields=["plan", "d_out", "use_kernel"])
 
 
 def pack_linear(kernel: jnp.ndarray, bits: int) -> PackedLinear:
@@ -106,35 +114,59 @@ def pack_linear_sdv(kernel: jnp.ndarray, plan: SDVPlan) -> SDVLinear:
     per-output-channel quantization stored as SDV words).  A stacked
     [L, d_in, d_out] kernel (scanned blocks) packs each layer with the
     shared plan and keeps the layer axis on every data field."""
-    from repro.kernels import ops
     assert kernel.ndim in (2, 3), kernel.shape
     if kernel.ndim == 3:
-        per = [pack_linear_sdv(kernel[i], plan)
-               for i in range(kernel.shape[0])]
-        return SDVLinear(words=jnp.stack([p.words for p in per]),
-                         scale=jnp.stack([p.scale for p in per]),
-                         plan=plan, d_out=kernel.shape[-1])
+        # one layer at a time, written in place into the stacked
+        # result: neither the temporaries of a whole [L, d_in, d_out]
+        # stack nor a list-then-stack copy of its words ever exists
+        words = scale = None
+        for i in range(kernel.shape[0]):
+            layer = pack_linear_sdv(kernel[i], plan)
+            if words is None:
+                words = jnp.zeros((kernel.shape[0],) + layer.words.shape,
+                                  layer.words.dtype)
+                scale = jnp.zeros((kernel.shape[0],) + layer.scale.shape,
+                                  layer.scale.dtype)
+            words, scale = _put_layer(words, scale, i, layer.words,
+                                      layer.scale)
+        return SDVLinear(words=words, scale=scale, plan=plan,
+                         d_out=kernel.shape[-1])
     kf = kernel.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(kf), axis=0)
-    scale = quantizer.symmetric_scale(amax, plan.w_a)
+    scale = quantizer.symmetric_scale(jnp.max(jnp.abs(kf), axis=0),
+                                      plan.w_a)
     q = quantizer.symmetric_qvalues(kf, scale, plan.w_a).astype(jnp.int32)
-    words = ops.prepare_sdv_weights(q.T, plan)               # [d_in, G]
+    del kf
+    # the word packing is integer-exact, so it runs as one compiled
+    # program per shape instead of a stream of eager limb ops
+    words = _pack_sdv_words(q, plan)                         # [d_in, G]
     return SDVLinear(words=words, scale=scale.astype(jnp.float32),
                      plan=plan, d_out=kernel.shape[-1])
 
 
-def sdv_matmul_apply(qw: SDVLinear, x: jnp.ndarray,
-                     use_kernel: Optional[bool] = None) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnums=1)
+def _pack_sdv_words(q: jnp.ndarray, plan: SDVPlan) -> jnp.ndarray:
+    from repro.kernels import ops
+    return ops.prepare_sdv_weights(q.T, plan)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _put_layer(words, scale, i, layer_words, layer_scale):
+    return words.at[i].set(layer_words), scale.at[i].set(layer_scale)
+
+
+def sdv_matmul_apply(qw: SDVLinear, x: jnp.ndarray) -> jnp.ndarray:
     """x [..., d_in] @ SDV-packed kernel -> [..., d_out] in x.dtype.
 
     Activations are dynamically quantized per row (symmetric,
     ``plan.w_b`` bits); the integer GEMM goes through the
     ``packed_matmul`` dispatch layer, the two scales dequantize the
-    exact int32 lane results.  ``use_kernel`` defaults to the backend:
-    Pallas on TPU, the pure-jnp SDV-word decode path on CPU (interpret
-    mode is for tests, not serving).
+    exact int32 lane results.  The route follows the container's
+    ``use_kernel``; unpinned, the backend: Pallas on TPU, the pure-jnp
+    SDV-word decode path on CPU (interpret mode is for tests, not
+    serving).
     """
     from repro.kernels import ops
+    use_kernel = qw.use_kernel
     if use_kernel is None:
         use_kernel = jax.default_backend() != "cpu"
     xf = x.astype(jnp.float32)

@@ -386,6 +386,11 @@ class Engine:
                 rows=rows)
         return self._qparams_by_rows[rows]
 
+    def packed_params(self, batch: int) -> Any:
+        """The packed parameter tree the buckets of width ``batch``
+        serve (plans resolved on first use, then shared)."""
+        return self._qparams(batch)
+
     def _make_state(self, bucket: BucketShape, qparams: Any
                     ) -> _BucketState:
         from repro.models import init_cache, values, Rules

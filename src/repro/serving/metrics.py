@@ -104,7 +104,9 @@ def packed_layer_stats(qparams: Any, rows: int,
                 else 1
             macs = rows * d_in * tree.d_out * stack
             route, reason = ops.select_packed_route(
-                rows, plan=tree.plan, use_kernel=use_kernel, explain=True)
+                rows, plan=tree.plan, explain=True,
+                use_kernel=(use_kernel if tree.use_kernel is None
+                            else tree.use_kernel))
             wide = macs if route == "ref" else \
                 sdv_num_multiplies(rows, tree.d_out, d_in,
                                    tree.plan) * stack

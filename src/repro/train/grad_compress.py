@@ -33,28 +33,12 @@ from jax.sharding import PartitionSpec as PS
 
 from repro.core import signed_split
 
-try:                                    # jax >= 0.6: promoted to jax.shard_map
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 #: bits per lane of the packed gradient word (two lanes per int32)
 GRAD_LANE = 16
 #: devices whose +/-127 lane contributions still fit a signed 16-bit
 #: lane sum: 127 * 258 = 32766 <= 2^15 - 1 (and the int32 word total
 #: 127 * 65537 * 258 stays under 2^31)
 MAX_PACKED_DEVICES = 258
-
-
-def _shard_map_unchecked(body, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off on any jax version
-    (the kwarg was renamed ``check_rep`` -> ``check_vma``)."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
 
 
 def pack_grad_words(q: jnp.ndarray) -> jnp.ndarray:
@@ -118,12 +102,7 @@ def compress_psum(g: jnp.ndarray, err: jnp.ndarray, axes: Sequence[str],
         qsum = red
     n = 1
     for a in axes:
-        # jax.lax.axis_size only exists on newer jax; psum of a unit is
-        # the portable spelling (constant-folded, no real collective).
-        if hasattr(jax.lax, "axis_size"):
-            n *= jax.lax.axis_size(a)
-        else:
-            n *= jax.lax.psum(1, a)
+        n *= jax.lax.axis_size(a)
     g_hat = (qsum.astype(jnp.float32) * scale / n).astype(g.dtype)
     return g_hat, new_err
 
@@ -157,5 +136,5 @@ def compressed_allreduce(grads: Any, errs: Any, mesh,
     in_spec = jax.tree_util.tree_map(lambda _: PS(axis), grads)
     out_spec = (jax.tree_util.tree_map(lambda _: PS(), grads),
                 jax.tree_util.tree_map(lambda _: PS(axis), grads))
-    return _shard_map_unchecked(body, mesh, (in_spec, in_spec),
-                                out_spec)(grads, errs)
+    return jax.shard_map(body, mesh=mesh, in_specs=(in_spec, in_spec),
+                         out_specs=out_spec, check_vma=False)(grads, errs)

@@ -58,7 +58,10 @@ def schedule(cfg: OptConfig, step: jnp.ndarray) -> jnp.ndarray:
 
 def init(cfg: OptConfig, params: Any) -> Any:
     def zeros_like_state(p):
-        z = jnp.zeros(p.shape, jnp.float32)
+        # zeros_like keeps the parameter's sharding: on a mesh the
+        # moments start sharded like their weights (FSDP), never whole
+        # on the default device
+        z = jnp.zeros_like(p, dtype=jnp.float32)
         if cfg.moments_8bit and p.ndim >= 1 and p.size >= 4096:
             return _q8(z)
         return z

@@ -19,7 +19,7 @@ dispatch layer:
     silently corrupts one datapath fails here by name.
   * NO-X64 SWEEP (``make test-wide-words``): every enumerable
     DSP48E2/DSP58 conv2d / conv1d / matmul plan executes its kernel
-    route inside ``jax.experimental.disable_x64()`` and must match the
+    route inside ``jax.enable_x64(False)`` and must match the
     oracle bit-exactly — the tentpole acceptance surface for the
     two-limb representation.  The int64 single-word path survives ONLY
     as the oracle these sweeps compare against.
@@ -381,7 +381,7 @@ def test_conv_sdv_plan_overrides_bit_exact():
 
 # ---------------------------------------------------------------------------
 # no-x64 sweep: every enumerable DSP48E2/DSP58 plan on its kernel route
-# inside jax.experimental.disable_x64() — the tentpole acceptance
+# inside jax.enable_x64(False) — the tentpole acceptance
 # surface for the two-limb int32 representation.  The oracle (`want`)
 # is computed in numpy OUTSIDE the context.
 # ---------------------------------------------------------------------------
@@ -409,7 +409,7 @@ def test_matmul_wide_word_no_x64(ly, plan):
               if plan.signed_b else (0, 1 << plan.w_b))
     x_np = rng.integers(lo, hi, (ly.rows, ly.k))
     want = x_np @ w_np.T
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         route = ops.select_packed_route(ly.rows, plan=plan)
         assert route in ("sdv_matmul", "sdv_matvec"), (plan, route)
         words = ops.prepare_sdv_weights(
@@ -442,7 +442,7 @@ def test_conv2d_wide_word_no_x64(plan):
                         (ly.c_out, ly.c_in, ly.kh, ly.kw))
     want = np.asarray(ref.conv2d_int_ref(jnp.asarray(x_np),
                                          jnp.asarray(w_np)))
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         route = ops.select_conv_route(x_np.shape, w_np.shape, plan=plan)
         assert route != "ref", (plan, route)
         y = ops.packed_conv2d(jnp.asarray(x_np, jnp.int32),
@@ -470,7 +470,7 @@ def test_conv1d_wide_word_no_x64(plan):
     x_np = rng.integers(-8, 8, (2, 13, 6))
     want = np.asarray(ref.conv1d_causal_ref(jnp.asarray(x_np),
                                             jnp.asarray(taps_np)))
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         assert ops.select_conv1d_route(plan) == "bseg_conv1d", plan
         kappa, tsum = ops.prepare_bseg_taps(
             jnp.asarray(taps_np, jnp.int32), plan)
@@ -485,7 +485,7 @@ def test_planner_wide_choice_no_x64():
     """With x64 off the auto planner still picks the wide DSP48E2 n=3
     W4A8 plan (the density win that motivated the limb refactor) and
     prices it as a kernel route."""
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         choice = planner.choose_plan(
             planner.matmul_spec("m", 4, 256, 512, w_bits=4, a_bits=8))
         assert choice.plan.spec.name in WIDE_SPECS, choice.plan
@@ -524,7 +524,7 @@ def test_limb_carry_property(a, b, sh, width):
     the kernels."""
     from repro.core import limbs as L
     m64 = (1 << 64) - 1
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         la, lb = _limbs_of(a), _limbs_of(b)
         assert _int_of(L.add(la, lb)) == (a + b) & m64
         assert _int_of(L.sub(la, lb)) == (a - b) & m64
@@ -549,7 +549,7 @@ def test_limb_carry_deterministic():
             (1 << 63) - 1, 1 << 63, m64, 0xDEADBEEFCAFEBABE]
     rng = np.random.default_rng(17)
     rand = [int(v) for v in rng.integers(0, m64, 12, dtype=np.uint64)]
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         for a in edge + rand[:6]:
             for b in edge[:4] + rand[6:]:
                 la, lb = _limbs_of(a), _limbs_of(b)

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as PS
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 out = {"n_devices": jax.device_count()}
 
 # --- int8 gradient all-reduce with error feedback across 4 DP ranks ---
@@ -80,7 +81,7 @@ out["losses"] = losses
 from repro.train import checkpoint
 ckdir = os.environ["CK_DIR"]
 checkpoint.save(ckdir, 1, pv)
-mesh2 = jax.make_mesh((8, 1), ("data", "model"))
+mesh2 = make_mesh((8, 1), ("data", "model"))
 rules2 = rules_for_mesh(mesh2, fsdp=False)
 pt2 = init_params(cfg, rules2, None)
 ps2 = specs(pt2)

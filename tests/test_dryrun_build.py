@@ -1,7 +1,7 @@
 """Launch-path tests: dry-run cell construction (specs, shardings,
 shape-skip logic) without the 512-device compile — the full compile
-matrix runs via `python -m repro.launch.dryrun` (results committed in
-EXPERIMENTS.md §Dry-run).  These tests run on the subprocess mesh."""
+matrix runs via `python -m repro.launch.dryrun`.  These tests run on
+the subprocess mesh."""
 import json
 import os
 import subprocess
@@ -16,7 +16,8 @@ import json
 import jax
 
 # a miniature production mesh with the same axis names
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 
 from repro.configs.base import SHAPES
 from repro.configs.registry import ARCHS, get_arch
@@ -42,7 +43,7 @@ for arch in ("tinyllama-1.1b", "mamba2-130m", "phi3.5-moe-42b-a6.6b"):
             with shard_ctx.use_rules(rules):
                 c = jax.jit(fn, in_shardings=in_sh,
                             donate_argnums=donate).lower(*args).compile()
-        assert DR.cost_analysis_dict(c).get("flops", 0) > 0
+        assert c.cost_analysis().get("flops", 0) > 0
 
 # skip rules propagate
 for a in ARCHS.values():

@@ -73,7 +73,7 @@ def test_packed_conv2d_bit_exact(shape, mode):
     b, h, w, cin, cout, kh, kw = shape
     x = _rand_x(b, h, w, cin, zero_point=8)
     wt = _rand_conv(cin, cout, kh, kw)
-    _check(x, wt, PLAN, mode, zero_point=8, block_h=4, block_co=8)
+    _check(x, wt, PLAN, mode, zero_point=8, block_co=8)
 
 
 @pytest.mark.parametrize("wk,wi", [(2, 2), (2, 4), (3, 3), (4, 4), (5, 2)])

@@ -3,6 +3,8 @@ layer: bit-exactness against the pure-jnp oracles over batch shapes,
 bitwidth plans (signed and unsigned elements), ragged M/K; and the
 dispatch table itself (each (batch, plan, backend) combination selects
 the intended kernel)."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -175,7 +177,8 @@ def test_sdv_linear_apply_matches_materialized():
     kernel = jnp.asarray(RNG.standard_normal((48, 33)).astype(np.float32))
     qw = pack_linear_sdv(kernel, plan)
     x = jnp.asarray(RNG.standard_normal((5, 48)).astype(np.float32))
-    y = np.asarray(sdv_matmul_apply(qw, x, use_kernel=True))
+    y = np.asarray(sdv_matmul_apply(
+        dataclasses.replace(qw, use_kernel=True), x))
     # same quantized weights, dense float path; the only difference is
     # the 8-bit dynamic activation quantization
     want = np.asarray(x @ materialize(qw, jnp.float32))

@@ -620,13 +620,13 @@ def test_cli_main_smoke(tmp_path, capsys):
 
 def test_cli_main_no_x64(tmp_path, capsys):
     """The planner CLI must not force-enable x64 (the wide words run
-    as two int32 limb planes): under ``disable_x64`` the table still
+    as two int32 limb planes): under ``jax.enable_x64(False)`` the table still
     builds, x64 stays off afterwards, and every wide-datapath layer
     the table prints is priced on a kernel route."""
     import jax
     from repro.planner.__main__ import main
     out_json = str(tmp_path / "plan.json")
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         assert main(["--arch", "ultranet", "--smoke", "--json",
                      out_json]) == 0
         assert not jax.config.jax_enable_x64, \

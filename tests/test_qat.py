@@ -26,7 +26,7 @@ decodes.  Concretely —
     device bound, and refuses past it.
   * NO-X64: the whole training path — STE packed forward on a wide
     datapath, Q8 optimizer moments, grad word packing — runs inside
-    ``jax.experimental.disable_x64()`` unchanged.
+    ``jax.enable_x64(False)`` unchanged.
 """
 import zlib
 
@@ -414,10 +414,9 @@ def test_compressed_allreduce_guards_device_bound():
 
 def test_training_path_runs_without_x64():
     """STE packed forward on a wide datapath, Q8 moments, grad word
-    packing — all inside ``disable_x64`` (conftest enables x64 for the
+    packing — all inside ``jax.enable_x64(False)`` (conftest enables x64 for the
     oracles; the training path must never need it)."""
-    from jax.experimental import disable_x64
-    with disable_x64():
+    with jax.enable_x64(False):
         # STE forward on a wide (two-limb) datapath plan
         ly = planner.matmul_spec("t", 2, 24, 10, w_bits=4, a_bits=8)
         from repro.core.datapath import DATAPATHS
