@@ -92,7 +92,7 @@ from .faults import FaultPlan, InjectedFault, WaveFaults
 from .queue import (Backpressure, BucketShape, BucketUnavailable,
                     ContinuousBatcher, DeadlineInfeasible, Request,
                     bucket_for, default_buckets)
-from .metrics import EngineMetrics, packed_utilization
+from .metrics import SPANS, EngineMetrics, packed_utilization
 
 PLAN_POLICIES = ("default", "auto", "cache")
 
@@ -222,7 +222,6 @@ class _WaveState:
     spec_tokens: int = 0            # tokens those rounds emitted
     draft_wall_s: float = 0.0
     verify_wall_s: float = 0.0
-    busy_slot_steps: int = 0        # occupied slots summed over iters
     requests: int = 0               # admitted incl. mid-wave joiners
 
 
@@ -617,6 +616,14 @@ class Engine:
         recorded).  ``submit_t`` back-dates the latency clock to the
         request's true arrival time (load generators submitting after
         a wave held the loop)."""
+        with SPANS.span("engine.submit"):
+            rid = self._submit(prompt, new_tokens, deadline, submit_t)
+        SPANS.begin("request.queue", rid)
+        return rid
+
+    def _submit(self, prompt: Sequence[int], new_tokens: int,
+                deadline: Optional[float],
+                submit_t: Optional[float]) -> int:
         if not self._admitting:
             raise EngineDraining("engine is draining: not admitting")
         # admission must see *current* health: a cooldown that expired
@@ -673,6 +680,10 @@ class Engine:
         assert rid not in self.outcomes, \
             (rid, outcome, self.outcomes[rid])       # exactly once
         self.outcomes[rid] = {"outcome": outcome, "detail": detail}
+        # a request shed or failed before its first token leaves no
+        # span of its own open
+        SPANS.end("request.queue", rid)
+        SPANS.end("request.prefill", rid)
 
     def _shed(self, requests: List[Request]) -> None:
         for r in requests:
@@ -721,6 +732,8 @@ class Engine:
                               sessions=SessionTable(bucket.batch))
             self._states[bucket.key] = st
         kind = getattr(error, "kind", type(error).__name__)
+        for r in unfinished:                    # back to a queue
+            self._spans_requeued(r.rid)
         st.fail_streak += 1
         self.metrics.record_wave_failure(bucket.key, kind)
         failed_probe = st.health == "probing"
@@ -753,19 +766,24 @@ class Engine:
         partial bucket — the drain path); when no bucket flushes,
         serve one degraded-fallback request if any is pending.
         Returns the completions this call produced."""
-        self.metrics.sample_depth(self.depth())
-        self._tick_breakers()
-        self._shed_expired()
-        if self._active is not None:
-            st = self._states[self._active]
-            return self._advance_and_settle(st, self.wave_quantum)
-        got = self.batcher.ready(est_wave_s=self._est_wave_s(),
-                                 force=force)
-        if got is not None:
-            return self._run_wave(*got)
-        if self._fallback_pending:
-            return self._run_fallback(self._fallback_pending.pop(0))
-        return []
+        with SPANS.span("engine.step"):
+            with SPANS.span("engine.schedule"):
+                self.metrics.sample_depth(self.depth())
+                self._tick_breakers()
+                self._shed_expired()
+                st = got = None
+                if self._active is not None:
+                    st = self._states[self._active]
+                else:
+                    got = self.batcher.ready(est_wave_s=self._est_wave_s(),
+                                             force=force)
+            if st is not None:
+                return self._advance_and_settle(st, self.wave_quantum)
+            if got is not None:
+                return self._run_wave(*got)
+            if self._fallback_pending:
+                return self._run_fallback(self._fallback_pending.pop(0))
+            return []
 
     def drain(self, close: bool = False) -> List[Completion]:
         """Finish every queued request without admitting new ones
@@ -840,6 +858,7 @@ class Engine:
         start_t = self.clock()
         for r in requests:
             st.sessions.join(Session(request=r, start_t=start_t))
+            self._spans_joined(r.rid)
         st.cache = st.cache0                    # pristine, reused
         window = max(self._expected_iters(requests), 1)
         injecting = inject and self.faults is not None
@@ -849,19 +868,33 @@ class Engine:
                              inject=injecting, sched_window=window,
                              skew_s=wf.skew_s, requests=len(requests))
 
-    def _pull_joiners(self, st: _BucketState) -> None:
+    def _spans_joined(self, rid: int) -> None:
+        """Request ``rid`` took a slot: its queue wait ends and its
+        prefill (to the first token) begins."""
+        SPANS.end("request.queue", rid)
+        SPANS.begin("request.prefill", rid)
+
+    def _spans_requeued(self, rid: int) -> None:
+        """Request ``rid`` left a failed wave for a queue: its prefill
+        ends and it waits again."""
+        SPANS.end("request.prefill", rid)
+        SPANS.begin("request.queue", rid)
+
+    def _pull_joiners(self, st: _BucketState) -> int:
         """Fill freed slots from the bucket's queue *mid-wave*: the
         slot's cache column is reset (``reset_slot``) so the joining
         session starts from position 0 while its neighbours keep
         decoding — the per-slot ``index[B]`` contract is what makes
-        this sound.  Expired requests found here are shed, not run."""
+        this sound.  Expired requests found here are shed, not run.
+        Returns the number that joined."""
         free = st.sessions.free_slots()
         if not st.wave.allow_joins or free == 0:
-            return
+            return 0
         pulled = self.batcher.take(st.bucket, free)
         if not pulled:
-            return
+            return 0
         now = self.clock()
+        joined = 0
         for r in pulled:
             tr = r.time_remaining(now)
             if tr is not None and tr <= 0:
@@ -872,6 +905,9 @@ class Engine:
             st.cache = self._reset(st.cache, slot)
             st.wave.requests += 1
             self.metrics.record_join()
+            self._spans_joined(r.rid)
+            joined += 1
+        return joined
 
     def _wave_iteration(self, st: _BucketState) -> List[Completion]:
         """One iteration of the active wave: slots with teacher-forced
@@ -884,7 +920,8 @@ class Engine:
         import jax
         import jax.numpy as jnp
         w, bucket, table = st.wave, st.bucket, st.sessions
-        self._pull_joiners(st)
+        with SPANS.span("engine.join") as span:
+            span.n = self._pull_joiners(st)
         if w.inject and w.iters - w.sched_base >= w.sched_window:
             # a continuous wave can outlive any batch: redraw the fault
             # schedule every expected-wave window so injection
@@ -896,7 +933,7 @@ class Engine:
                 and w.iters - w.sched_base == w.faults.fail_at_step:
             raise InjectedFault(
                 "kernel_loss", f"{bucket.key} step {w.iters}")
-        b, vocab = bucket.batch, self.cfg.vocab
+        b = bucket.batch
         active = table.active()
         c = self.prefill_chunk
         # spec mode forces the chunked-prefill path for teacher-forced
@@ -912,27 +949,30 @@ class Engine:
         decoding = [(slot, s) for slot, s in active
                     if slot not in pref_slots]
         w.iters += 1
+        self.metrics.record_iteration(bucket.key, busy_slots=len(active),
+                                      slots=b,
+                                      prefill_chunks=len(prefilling))
         if prefilling:
-            t0 = self.clock()
-            cache = st.cache
-            for slot, s in prefilling:
-                n = min(c, s.prompt_len - 1 - s.fed)
-                toks = np.full((1, c), self.pad_token, np.int32)
-                toks[0, :n] = s.request.prompt[s.fed:s.fed + n]
-                # prefill feeds the TARGET cache only: the draft forks
-                # it per round (self-speculation shares the layout),
-                # so spec mode pays no second prefill pass
-                cache = self._pre(st.qparams, cache, slot,
-                                  jnp.asarray(toks),
-                                  jnp.asarray([n], np.int32))
-                s.fed += n
-            # sync INSIDE the timed loop: the prefill EMA must include
-            # device time
-            jax.block_until_ready(cache["index"])
+            with SPANS.span("engine.prefill", n=len(prefilling)):
+                t0 = self.clock()
+                cache = st.cache
+                for slot, s in prefilling:
+                    n = min(c, s.prompt_len - 1 - s.fed)
+                    toks = np.full((1, c), self.pad_token, np.int32)
+                    toks[0, :n] = s.request.prompt[s.fed:s.fed + n]
+                    # prefill feeds the TARGET cache only: the draft
+                    # forks it per round (self-speculation shares the
+                    # layout), so spec mode pays no second prefill pass
+                    cache = self._pre(st.qparams, cache, slot,
+                                      jnp.asarray(toks),
+                                      jnp.asarray([n], np.int32))
+                    s.fed += n
+                # sync INSIDE the timed loop: the prefill EMA must
+                # include device time
+                jax.block_until_ready(cache["index"])
             st.cache = cache
             w.prefill_steps += len(prefilling)
             w.prefill_wall_s += max(self.clock() - t0, 1e-9)
-            w.busy_slot_steps += len(prefilling)
         if not decoding:
             return []
         if self.speculative and st.spec_on:
@@ -947,27 +987,36 @@ class Engine:
                 # below.  st.cache was not reassigned, so the pending
                 # tokens are still unconsumed.
                 self._degrade_spec(st, e)
-        t0 = self.clock()
-        toks = np.full((b, 1), self.pad_token, np.int32)
-        for slot, s in decoding:
-            # the next token this slot consumes: its own prompt while
-            # teacher-forcing (fed is this slot's cache position), its
-            # last generated token afterwards
-            toks[slot, 0] = s.request.prompt[s.fed] \
-                if s.fed < s.prompt_len else s.tokens[-1]
-        adv = np.ones((b,), np.int32)
-        for slot in pref_slots:     # mid-prefill slots: no KV write,
-            adv[slot] = 0           # no index move, logits discarded
-        logits, cache = self._dec(st.qparams, st.cache, jnp.asarray(toks),
-                                  jnp.asarray(adv))
-        # sync INSIDE the timed loop: per-step wall clock and
-        # completion latencies must include device time
-        jax.block_until_ready(logits)
+        with SPANS.span("engine.decode", n=len(decoding)):
+            t0 = self.clock()
+            toks = np.full((b, 1), self.pad_token, np.int32)
+            for slot, s in decoding:
+                # the next token this slot consumes: its own prompt
+                # while teacher-forcing (fed is this slot's cache
+                # position), its last generated token afterwards
+                toks[slot, 0] = s.request.prompt[s.fed] \
+                    if s.fed < s.prompt_len else s.tokens[-1]
+            adv = np.ones((b,), np.int32)
+            for slot in pref_slots:     # mid-prefill slots: no KV write,
+                adv[slot] = 0           # no index move, logits discarded
+            logits, cache = self._dec(st.qparams, st.cache,
+                                      jnp.asarray(toks), jnp.asarray(adv))
+            # sync INSIDE the timed loop: per-step wall clock and
+            # completion latencies must include device time
+            jax.block_until_ready(logits)
         st.cache = cache
         w.decode_steps += 1
         w.decode_wall_s += max(self.clock() - t0, 1e-9)
-        w.busy_slot_steps += len(decoding)
-        last = np.asarray(logits[:, -1, :vocab])
+        with SPANS.span("engine.sample"):
+            return self._sample(st, decoding, logits)
+
+    def _sample(self, st: _BucketState,
+                decoding: List[Tuple[int, Session]],
+                logits: Any) -> List[Completion]:
+        """Read a decode step's logits back, take each decoding slot's
+        greedy token, and retire the sessions that are done."""
+        bucket, table = st.bucket, st.sessions
+        last = np.asarray(logits[:, -1, :self.cfg.vocab])
         finish_t = self.clock()
         completions: List[Completion] = []
         emitted = 0
@@ -979,6 +1028,8 @@ class Engine:
             tok = int(last[slot].argmax())
             s.tokens.append(tok)
             emitted += 1
+            if len(s.tokens) == 1:
+                SPANS.end("request.prefill", s.request.rid)
             if s.done():                        # leave mid-wave: free slot
                 table.leave(slot)
                 comp = Completion(
@@ -1069,7 +1120,6 @@ class Engine:
         w.spec_rounds += 1
         w.draft_wall_s += draft_s
         w.verify_wall_s += verify_s
-        w.busy_slot_steps += len(decoding)
         finish_t = self.clock()
         completions: List[Completion] = []
         accepted: List[int] = []
@@ -1077,6 +1127,8 @@ class Engine:
             t = int(acc[slot])
             if s.fed < s.prompt_len:
                 s.fed += 1                      # consumed: last prompt tok
+            if t and not s.tokens:
+                SPANS.end("request.prefill", s.request.rid)
             s.tokens.extend(int(g) for g in greedy[slot, :t])
             accepted.append(t)
             w.spec_tokens += t
@@ -1126,8 +1178,7 @@ class Engine:
             st.bucket.key, steps=w.iters,
             wall_s=(w.prefill_wall_s + w.decode_wall_s + w.draft_wall_s
                     + w.verify_wall_s + w.skew_s),
-            requests=w.requests, busy_slot_steps=w.busy_slot_steps,
-            slot_steps=w.iters * st.bucket.batch)
+            requests=w.requests)
         st.wave = None
         st.cache = None
 

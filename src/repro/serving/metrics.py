@@ -18,12 +18,20 @@ parameter containers with the existing accounting
 dispatch route each layer's plan lands on (a ref-routed layer counts
 density 1 — it never reaches the packed datapath; memory-packed
 layers likewise, their packing is HBM-only).
+
+Spans (``Spans``) are the engine's own record of where a step's host
+time goes: named intervals on the host's ``perf_counter_ns`` clock,
+kept in memory while recording is on and free while it is off, with
+one anchor pair that puts them on the wall clock a profiler trace
+starts from.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Dict, List, Optional
+import time
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 from repro.ioutil import atomic_write_json
 
@@ -158,6 +166,190 @@ def packed_utilization(qparams: Any, rows: int,
 
 
 # ---------------------------------------------------------------------------
+# spans: where the host's time goes, on a clock a device trace can share
+# ---------------------------------------------------------------------------
+
+#: the ``jax.monitoring`` duration event of one XLA backend compile
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: spans kept per recording; past it they are counted, not kept
+SPAN_CAP = 1 << 18
+
+
+class SpanRecord(NamedTuple):
+    """One recorded span.  ``parent`` is the index of the span that was
+    open around it (``None`` at the top, and for a request's spans,
+    which outlive the call that opens them); ``rid`` the request it
+    belongs to; ``n`` a count taken at its boundary (chunks dispatched,
+    slots decoding, joiners).  ``end_ns`` is ``None`` while open."""
+    name: str
+    start_ns: int
+    end_ns: Optional[int]
+    parent: Optional[int]
+    rid: Optional[int]
+    n: Optional[int]
+
+
+class _NoSpan:
+    """What ``Spans.span`` returns while recording is off: one shared
+    object, so a span costs no clock read and no allocation."""
+    __slots__ = ()
+    n = property(lambda self: None, lambda self, value: None)
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """An open span; set ``n`` inside the block to record a count
+    known only at its end."""
+    __slots__ = ("_spans", "_i", "name", "rid", "n")
+
+    def __init__(self, spans: "Spans", name: str, rid: Optional[int],
+                 n: Optional[int]):
+        self._spans, self._i = spans, -1
+        self.name, self.rid, self.n = name, rid, n
+
+    def __enter__(self) -> "_Span":
+        self._i = self._spans._open(self.name, self.rid, self.n,
+                                    nested=True)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._spans._close(self._i, self.n, nested=True)
+        return False
+
+
+class Spans:
+    """The serving program's span recorder, off until ``start()``.
+
+    While on it keeps up to ``SPAN_CAP`` spans in memory (``dropped``
+    counts the rest), stamped with ``time.perf_counter_ns()`` — never the
+    engine's injectable clock, which tests fake.  ``start()`` takes one
+    anchor pair (``time.time_ns()``, ``perf_counter_ns()``), so
+    ``timeline(origin_ns)`` can put every span on the wall clock less
+    ``origin_ns``: with a profiler trace's ``profile_start_time`` as the
+    origin, that is the clock of the trace's own events.  A backend
+    compile reported while a span is open (``COMPILE_EVENT``) becomes a
+    ``jax.compile`` span inside it."""
+
+    def __init__(self):
+        self.on = False
+        self.dropped = 0
+        self.anchor: Optional[Tuple[int, int]] = None
+        self._records: List[list] = []
+        self._stack: List[int] = []                 # open nested spans
+        self._pending: Dict[Tuple[str, int], int] = {}   # open request spans
+
+    # -- switching ----------------------------------------------------------
+
+    def start(self) -> None:
+        """Forget what was recorded, take the anchor pair, record."""
+        import jax
+        self._records, self._stack, self._pending = [], [], {}
+        self.dropped = 0
+        self.anchor = (time.time_ns(), time.perf_counter_ns())
+        if not self.on:
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+        self.on = True
+
+    def stop(self) -> List[SpanRecord]:
+        """Stop recording; returns what was recorded."""
+        import jax
+        if self.on:
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_duration)
+        self.on = False
+        return self.records()
+
+    # -- recording ----------------------------------------------------------
+
+    def span(self, name: str, rid: Optional[int] = None,
+             n: Optional[int] = None):
+        """A context manager that records ``name`` around its block,
+        nested in the span open around it."""
+        if not self.on:
+            return _NO_SPAN
+        return _Span(self, name, rid, n)
+
+    def begin(self, name: str, rid: int) -> None:
+        """Open request ``rid``'s span ``name``, to be closed by
+        ``end`` from another call (a request's queue wait or prefill
+        outlives the step that starts it); one already open stays
+        as it is."""
+        if self.on and (name, rid) not in self._pending:
+            i = self._open(name, rid, None, nested=False)
+            if i >= 0:
+                self._pending[(name, rid)] = i
+
+    def end(self, name: str, rid: int) -> None:
+        """Close request ``rid``'s open span ``name``, if there is one."""
+        if self.on:
+            i = self._pending.pop((name, rid), -1)
+            self._close(i, None, nested=False)
+
+    def _open(self, name: str, rid: Optional[int], n: Optional[int], *,
+              nested: bool) -> int:
+        if len(self._records) >= SPAN_CAP:
+            self.dropped += 1
+            return -1
+        i = len(self._records)
+        parent = self._stack[-1] if nested and self._stack else None
+        self._records.append([name, time.perf_counter_ns(), None, parent,
+                              rid, n])
+        if nested:
+            self._stack.append(i)
+        return i
+
+    def _close(self, i: int, n: Optional[int], *, nested: bool) -> None:
+        if i < 0:
+            return
+        rec = self._records[i]
+        rec[2] = time.perf_counter_ns()
+        if n is not None:
+            rec[5] = n
+        if nested and self._stack and self._stack[-1] == i:
+            self._stack.pop()
+
+    def _on_duration(self, event: str, duration_secs: float, **_) -> None:
+        if event != COMPILE_EVENT or not self._stack \
+                or len(self._records) >= SPAN_CAP:
+            return
+        end = time.perf_counter_ns()
+        self._records.append(["jax.compile", end - int(duration_secs * 1e9),
+                              end, self._stack[-1], None, None])
+
+    # -- reading ------------------------------------------------------------
+
+    def records(self) -> List[SpanRecord]:
+        """Every span recorded since ``start()``, in the order opened."""
+        return [SpanRecord(*r) for r in self._records]
+
+    def timeline(self, origin_ns: int = 0) -> List[SpanRecord]:
+        """``records()`` with their stamps moved to the wall clock (ns
+        since the epoch) less ``origin_ns``; a span still open keeps
+        ``end_ns`` ``None``."""
+        if self.anchor is None:
+            return []
+        shift = self.anchor[0] - self.anchor[1] - origin_ns
+        return [r._replace(start_ns=r.start_ns + shift,
+                           end_ns=None if r.end_ns is None
+                           else r.end_ns + shift)
+                for r in self.records()]
+
+
+#: the serving program's one recorder: every ``Engine`` records here
+SPANS = Spans()
+
+
+# ---------------------------------------------------------------------------
 # the engine-side registry
 # ---------------------------------------------------------------------------
 
@@ -168,7 +360,9 @@ class EngineMetrics:
     clock: Callable[[], float]
     latencies_s: List[float] = dataclasses.field(default_factory=list)
     queue_wait_s: List[float] = dataclasses.field(default_factory=list)
-    depth_samples: List[int] = dataclasses.field(default_factory=list)
+    depth_samples: int = 0          # queue depth, sampled every step()
+    depth_sum: int = 0
+    depth_max: int = 0
     rejected: int = 0
     rejected_infeasible: int = 0    # admission control: hopeless deadline
     malformed: int = 0              # rejected at request validation
@@ -188,6 +382,7 @@ class EngineMetrics:
     # target wave is the speedup currency BENCH_10 sweeps.
     decode_launches: int = 0        # plain decode programs dispatched
     decode_tokens: int = 0          # tokens those launches emitted
+    prefill_chunks: int = 0         # prefill programs (one slot's chunk)
     spec_iters: int = 0             # draft+verify rounds completed
     spec_tokens: int = 0            # tokens those rounds emitted
     spec_draft_wall_s: float = 0.0
@@ -218,13 +413,10 @@ class EngineMetrics:
         self.finished_t = finish_t
 
     def record_wave(self, bucket_key: str, *, steps: int, wall_s: float,
-                    requests: int, busy_slot_steps: int = 0,
-                    slot_steps: int = 0) -> None:
+                    requests: int) -> None:
         self.waves += 1
         self.wave_steps += steps
         self.wave_wall_s += wall_s
-        self.busy_slot_steps += busy_slot_steps
-        self.slot_steps += slot_steps
         b = self.per_bucket.setdefault(
             bucket_key, {"waves": 0, "steps": 0, "wall_s": 0.0,
                          "requests": 0})
@@ -232,8 +424,21 @@ class EngineMetrics:
         b["steps"] += steps
         b["wall_s"] += wall_s
         b["requests"] += requests
-        b["busy_slot_steps"] = b.get("busy_slot_steps", 0) + busy_slot_steps
-        b["slot_steps"] = b.get("slot_steps", 0) + slot_steps
+
+    def record_iteration(self, bucket_key: str, *, busy_slots: int,
+                         slots: int, prefill_chunks: int) -> None:
+        """One wave iteration: the slots holding a session, the
+        bucket's width, and the prefill chunks it dispatches.  Counted
+        as it happens, so occupancy is true in a wave that never ends
+        (a continuous wave with joins)."""
+        self.busy_slot_steps += busy_slots
+        self.slot_steps += slots
+        self.prefill_chunks += prefill_chunks
+        b = self.per_bucket.setdefault(
+            bucket_key, {"waves": 0, "steps": 0, "wall_s": 0.0,
+                         "requests": 0})
+        b["busy_slot_steps"] = b.get("busy_slot_steps", 0) + busy_slots
+        b["slot_steps"] = b.get("slot_steps", 0) + slots
 
     def record_join(self) -> None:
         self.midwave_joins += 1
@@ -316,7 +521,9 @@ class EngineMetrics:
         self.fallback_waves += 1
 
     def sample_depth(self, depth: int) -> None:
-        self.depth_samples.append(depth)
+        self.depth_samples += 1
+        self.depth_sum += depth
+        self.depth_max = max(self.depth_max, depth)
 
     def set_bucket_utilization(self, bucket_key: str,
                                util: Dict[str, Any]) -> None:
@@ -351,7 +558,6 @@ class EngineMetrics:
         span = 0.0
         if self.started_t is not None and self.finished_t is not None:
             span = max(self.finished_t - self.started_t, 1e-9)
-        depth = self.depth_samples
         terminal = len(self.latencies_s) + self.shed + self.failed
         return {
             "requests_completed": len(self.latencies_s),
@@ -375,8 +581,9 @@ class EngineMetrics:
             "latency": latency_summary(self.latencies_s),
             "queue_wait": latency_summary(self.queue_wait_s),
             "queue_depth": {
-                "mean": (sum(depth) / len(depth)) if depth else 0.0,
-                "max": max(depth) if depth else 0,
+                "mean": (self.depth_sum / self.depth_samples
+                         if self.depth_samples else 0.0),
+                "max": self.depth_max,
             },
             "waves": {"count": self.waves, "steps": self.wave_steps,
                       "wall_s": self.wave_wall_s,
@@ -385,8 +592,11 @@ class EngineMetrics:
                       "slot_steps": self.slot_steps,
                       # wave occupancy: the fraction of compiled batch
                       # slots that held a live session, summed over
-                      # every wave iteration — the packed datapath is
-                      # only as busy as this number
+                      # every wave iteration so far — the packed
+                      # datapath is only as busy as this number.  It
+                      # counts the iterations of waves still running
+                      # and of waves that failed, which count, steps
+                      # and wall_s (ended waves only) leave out
                       "occupancy": (self.busy_slot_steps / self.slot_steps
                                     if self.slot_steps else 0.0)},
             "buckets": self.per_bucket,

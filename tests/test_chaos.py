@@ -246,6 +246,14 @@ def test_kernel_loss_falls_back_and_completes(tiny_setup):
     assert eng.metrics.fallback_waves == len(rids)
     fb_shape = eng._states[FALLBACK_KEY].bucket
     assert all(c.bucket_key == fb_shape.key for c in comps.values())
+    # occupancy counts the failed waves' iterations; the wave counts
+    # (count, steps, wall_s) hold only the waves that ended well
+    failed = eng.metrics.snapshot()["buckets"]["b2.s16"]
+    assert failed["waves"] == failed["steps"] == 0
+    assert failed["slot_steps"] > 0
+    assert failed["busy_slot_steps"] <= failed["slot_steps"]
+    fb = eng.metrics.snapshot()["buckets"][fb_shape.key]
+    assert fb["slot_steps"] == fb_shape.batch * fb["steps"]
     # bit-exact vs fault-free single-request runs in the same shape
     ref = Engine(cfg, params, compute="sdv", buckets=(fb_shape,),
                  plan_policy="default", clock=FakeClock())

@@ -398,3 +398,30 @@ def test_loadgen_drained_outcome(tiny_setup):
     assert counts["drained"] == snap["offered_requests"] > 0
     assert counts["rejected"] == 0
     assert snap["retried_submissions"] == 0     # never retried
+
+
+def test_engine_spec_ends_request_prefill_span(tiny_setup):
+    """The speculative path opens no engine spans of its own, but a
+    request's ``request.prefill`` span ends with its first tokens, not
+    when the request completes."""
+    from repro.serving.metrics import SPANS
+    cfg, params = tiny_setup
+    eng = Engine(cfg, params, buckets=(BucketShape(4, 64),),
+                 speculative=True, prefill_chunk=4)
+    SPANS.start()
+    try:
+        rid = eng.submit([1, 2, 3, 4, 5], new_tokens=8)
+        live = []
+        for _ in range(8):
+            eng.step(force=True)
+            live = [s for _, s in eng._states["b4.s64"].sessions.active()]
+            if not live or live[0].tokens:
+                break
+        assert live and live[0].tokens and not live[0].done()
+        pre = [r for r in SPANS.records() if r.name == "request.prefill"]
+        assert [r.rid for r in pre] == [rid] and pre[0].end_ns is not None
+        assert eng.metrics.snapshot()["speculative"]["rounds"] > 0
+        eng.drain()
+    finally:
+        SPANS.stop()
+    assert eng.outcomes[rid]["outcome"] == "ok"
