@@ -40,7 +40,9 @@ quant_matmul (no plan).  Explicit modes raise ``ValueError`` when their
 constraints cannot be met rather than silently falling back.  Both
 route selectors take ``explain=True`` to also return the *reason* for
 the decision — the planner cost model (``repro.planner.cost``) and the
-serve-time fallback log are built on it.
+serve-time fallback log are built on it.  The SDV rows take the blocks
+``sdv_blocks`` sizes from the call's shape unless the caller gives
+them.
 
 Dispatch table for ``packed_conv2d`` (mode -> kernel -> constraints):
 
@@ -203,23 +205,84 @@ def prepare_sdv_weights(w_int: jnp.ndarray, plan: SDVPlan) -> jnp.ndarray:
     return word.T                                           # [K, G]
 
 
+#: Vregs of one ``[br, bg]`` int32 array that a K step of the SDV
+#: GEMM/GEMV works on, at most (``sdv_blocks``).  On a v5e, a 16-row
+#: granite-8b layer takes 24% longer at 8 and 3.5% less at 32: past 16
+#: the loop's carried arrays outgrow the vreg file and the cost per
+#: vreg doubles (``scripts/sdv_tile_sweep.py``; PERF.md section 6).
+SDV_TILE_VREGS = 16
+#: Double-buffered storage-word block the tile rule stays within
+#: (v5e's scoped VMEM is 16 MiB; the rest holds activations, the
+#: accumulator and the outputs).
+SDV_WEIGHT_VMEM_BYTES = 8 << 20
+#: Most ragged-G padding, as a share of G, that a wider group block
+#: may add; past it the rule takes more, narrower blocks.
+SDV_MAX_G_PAD = 0.15
+
+
+def sdv_blocks(rows: int, k: int, g: int, plan: SDVPlan, *,
+               block_rows: int = 128,
+               block_g: Optional[int] = None,
+               block_k: Optional[int] = None,
+               target_vregs: int = SDV_TILE_VREGS) -> tuple[int, int, int]:
+    """The SDV kernel's ``(br, bg, bk)`` for an ``[rows, k] x [k, g]``
+    word GEMM; a block given explicitly is kept.
+
+    Each K step of the kernel runs one loop iteration over a
+    ``[br, bg]`` tile at a fixed cost plus a cost per vreg (PERF.md
+    section 5), so a thin row block takes a wide group block: G is
+    spread evenly over the fewest 128-lane-multiple blocks that keep
+    ``ceil(br/8) * ceil(bg/128)`` within ``target_vregs`` and add at
+    most ``SDV_MAX_G_PAD`` ragged padding; a full 128-row block takes
+    one 128-lane group block.  ``bk`` is the first of ``min(K, 512)``,
+    256 and 128 that divides K and whose double-buffered word block
+    fits ``SDV_WEIGHT_VMEM_BYTES``, else all of K; an explicit
+    ``block_k`` that does not divide K is all of K too.
+    """
+    br = min(rows, block_rows)
+    if block_g is None:
+        tiles = -(-g // 128)
+        max_tiles = max(1, target_vregs // -(-br // 8))
+        for nblocks in range(-(-tiles // max_tiles), tiles + 1):
+            block_g = 128 * -(-tiles // nblocks)
+            if block_g >= g:
+                block_g = g
+                break
+            if -(-g // block_g) * block_g - g <= SDV_MAX_G_PAD * g:
+                break
+    if block_k is None:
+        limbs = bseg_common.sdv_word_spec(plan).limbs
+        block_k = k
+        for bk in (min(k, 512), 256, 128):
+            if (k % bk == 0 and limbs * bk * min(block_g, g) * 4 * 2
+                    <= SDV_WEIGHT_VMEM_BYTES):
+                block_k = bk
+                break
+    else:
+        block_k = min(block_k, k)
+        if k % block_k:
+            block_k = k  # fall back to a single K block
+    return br, block_g, block_k
+
+
 def sdv_matvec(x_q: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
                m: int, use_kernel: bool = True,
-               block_b: int = 8, block_g: int = 128,
-               block_k: int = 512) -> jnp.ndarray:
+               block_b: int = 8, block_g: Optional[int] = None,
+               block_k: Optional[int] = None) -> jnp.ndarray:
     """Batched exact integer GEMV through the SDV datapath.
 
     x_q: [B, K] int8 activations, w_words: [K, G] from
-    ``prepare_sdv_weights``; returns [B, m] int32.
+    ``prepare_sdv_weights``; returns [B, m] int32.  Blocks left
+    ``None`` come from ``sdv_blocks``.
     """
     from . import sdv_matvec as sdv_kernel
     b, k = x_q.shape
     if use_kernel:
-        block_k = min(block_k, k)
-        if k % block_k:
-            block_k = k  # fall back to a single K block
+        bb, bg, bk = sdv_blocks(b, k, w_words.shape[-1], plan,
+                                block_rows=block_b, block_g=block_g,
+                                block_k=block_k)
         lanes = sdv_kernel.sdv_matvec(
-            x_q.T, w_words, plan=plan, bb=block_b, bg=block_g, bk=block_k,
+            x_q.T, w_words, plan=plan, bb=bb, bg=bg, bk=bk,
             interpret=_on_cpu())                            # [B, G, n]
         return lanes.reshape(b, -1)[:, :m]
     # pure-jnp path: unpack words back to ints and do the exact GEMV
@@ -333,8 +396,8 @@ def packed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
                   scale: Optional[jnp.ndarray] = None,
                   w_bits: Optional[int] = None,
                   mode: str = "auto", use_kernel: bool = True,
-                  block_rows: int = 128, block_g: int = 128,
-                  block_k: int = 512) -> jnp.ndarray:
+                  block_rows: int = 128, block_g: Optional[int] = None,
+                  block_k: Optional[int] = None) -> jnp.ndarray:
     """Batched packed matmul with kernel dispatch.
 
     Args:
@@ -349,6 +412,8 @@ def packed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
       scale / w_bits: dequantization scale ``[N]`` and element width —
         required by the ``quant_matmul`` route only.
       mode: a row of the dispatch table, or ``"auto"``.
+      block_rows / block_g / block_k: kernel blocks; on the SDV routes
+        a ``None`` block comes from ``sdv_blocks``.
 
     Returns:
       ``[..., M]`` — int32 (exact) on the SDV/ref integer routes, f32
@@ -364,7 +429,7 @@ def packed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
             raise ValueError(f"route {route!r} needs scale and w_bits")
         y = quant_matmul(x2, w, scale, w=w_bits,
                          use_kernel=(route == "quant_matmul"),
-                         block_m=block_rows, block_k=block_k)
+                         block_m=block_rows, block_k=block_k or 512)
         y = y if m is None else y[:, :m]
         return y.reshape(batch_shape + y.shape[-1:])
 
@@ -390,11 +455,10 @@ def packed_matmul(x: jnp.ndarray, w: jnp.ndarray, *,
 
     # sdv_matmul
     from . import sdv_matmul as sdvmm_kernel
-    bk = min(block_k, k)
-    if k % bk:
-        bk = k  # fall back to a single K block (no per-call pad copy)
+    br, bg, bk = sdv_blocks(x2.shape[0], k, g, plan, block_rows=block_rows,
+                            block_g=block_g, block_k=block_k)
     lanes = sdvmm_kernel.sdv_matmul(x2.astype(jnp.int32), w, plan=plan,
-                                    br=block_rows, bg=block_g, bk=bk,
+                                    br=br, bg=bg, bk=bk,
                                     interpret=_on_cpu())     # [R, G, n]
     y = lanes.reshape(x2.shape[0], -1)[:, :m]
     return y.reshape(batch_shape + (m,))

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.datapath import FP32M, INT32, plan_sdv
+from repro.core.datapath import DSP48E2, FP32M, INT32, plan_sdv
 from repro.kernels import ops, ref
 from repro.kernels.sdv_matmul import sdv_matmul, sdv_num_multiplies
 
@@ -43,6 +43,25 @@ def test_sdv_matmul_bit_exact(wa, signed_a):
                        br=8, bg=4, bk=32, interpret=True)
     got = np.asarray(lanes).reshape(12, -1)[:, :m]
     assert (got == x @ w_mat.T).all(), (plan, got[0, :4])
+
+
+@pytest.mark.parametrize("datapath", [INT32, DSP48E2], ids=lambda d: d.name)
+@pytest.mark.parametrize("rows,g", [(16, 1100), (64, 490)])
+def test_sdv_matmul_bit_exact_default_tiles(datapath, rows, g):
+    """The kernel at the tiles ``sdv_blocks`` picks for a thin row
+    block: a group block wider than 128 lanes over a ragged G, two K
+    blocks, on the one-limb int32 n=2 and two-limb DSP48E2 n=3 words."""
+    plan = plan_sdv(datapath, 4, 8, signed_a=True, signed_b=True,
+                    park_sign_bits=True)
+    m, k = g * plan.n - 1, 16
+    br, bg, _ = ops.sdv_blocks(rows, k, g, plan)
+    assert bg > 128 and g % bg, (rows, g, bg)
+    w_mat, x = _rand_wx(plan, m, k, (rows,))
+    words = ops.prepare_sdv_weights(jnp.asarray(w_mat), plan)
+    assert words.shape[-1] == g
+    y = ops.packed_matmul(jnp.asarray(x, jnp.int32), words, plan=plan,
+                          m=m, block_k=k // 2)
+    assert (np.asarray(y) == x @ w_mat.T).all(), (plan, br, bg)
 
 
 @pytest.mark.parametrize("batch_shape", [(1,), (3,), (20,), (2, 5)])
@@ -83,6 +102,49 @@ def test_ref_word_decode_roundtrip():
         words = ops.prepare_sdv_weights(jnp.asarray(w_mat), plan)
         back = np.asarray(ref.sdv_unpack_words_ref(words, plan=plan))
         assert (back.T[:m] == w_mat).all()
+
+
+# ---------------------------------------------------------------------------
+# the SDV tile rule (ops.sdv_blocks)
+# ---------------------------------------------------------------------------
+
+GRANITE_8B = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)]
+
+
+@pytest.mark.parametrize("datapath", [INT32, DSP48E2], ids=lambda d: d.name)
+@pytest.mark.parametrize("k,m", GRANITE_8B + [(64, 36)])
+@pytest.mark.parametrize("rows", [1, 3, 8, 16, 64, 128, 169])
+def test_sdv_blocks(datapath, k, m, rows):
+    """Every granite-8b projection (and UltraNet's 1x1 head) at decode,
+    chunk and GEMM row counts: within the vreg target and the VMEM
+    budget, K a multiple of ``bk``, ragged padding under 15%, and the
+    fixed ``[128, 128]`` tile from 128 rows up."""
+    plan = plan_sdv(datapath, 4, 8, signed_a=True, signed_b=True,
+                    park_sign_bits=True)
+    g = -(-m // plan.n)
+    br, bg, bk = ops.sdv_blocks(rows, k, g, plan)
+    limbs = 2 if datapath is DSP48E2 else 1
+    assert br == min(rows, 128)
+    assert bg == g or bg % 128 == 0
+    assert -(-br // 8) * -(-bg // 128) <= ops.SDV_TILE_VREGS
+    assert -(-g // bg) * bg - g < 0.15 * g
+    assert k % bk == 0
+    assert limbs * bk * bg * 4 * 2 <= ops.SDV_WEIGHT_VMEM_BYTES
+    if rows >= 128:
+        assert (br, bg) == (128, min(128, g))
+    elif rows <= 16 and g > 128:
+        assert bg > 128                 # a thin row block widens
+
+
+def test_sdv_blocks_explicit_pass_through():
+    plan = _plan(4, 8, True)
+    assert ops.sdv_blocks(16, 4096, 2048, plan, block_rows=8, block_g=256,
+                          block_k=128) == (8, 256, 128)
+    # rows cap the row block; a K block that does not divide K is all of K
+    assert ops.sdv_blocks(5, 100, 40, plan, block_rows=8, block_g=8,
+                          block_k=32) == (5, 8, 100)
+    # a given group block still sizes the default K block
+    assert ops.sdv_blocks(16, 4096, 2048, plan, block_g=128)[1:] == (128, 512)
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ cannot see.  The topology is described inside a module fixture (never
 at import: only one process may load the TPU library), and the tests
 skip when it cannot be described.  Shapes: gemma-2b decode and prefill
 GEMMs on the planner's two W4A8 plans (int32 n=2, DSP48E2 n=3),
+granite-8b's projections at the tiles ``ops.sdv_blocks`` picks,
 UltraNet's 416x416 conv stages, the mamba2-130m short conv, and the
 memory-packed ``quant_matmul``.
 """
@@ -25,6 +26,7 @@ from repro.kernels.sdv_matvec import sdv_matvec
 
 SDV_PLANS = {"int32": INT32, "dsp48e2": DSP48E2}
 GEMMA_DECODE = [(2048, 2560), (2048, 16384), (16384, 2048)]   # K -> M
+GRANITE_8B = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)]
 
 
 @pytest.fixture(scope="module")
@@ -79,6 +81,21 @@ def test_sdv_matmul_compiles(compile_tpu, datapath, k, m):
     hlo = compile_tpu(
         lambda x, w: sdv_matmul(x, w, plan=plan, interpret=False),
         ((128, k), jnp.int32), (_word_shape(plan, k, m), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("datapath", sorted(SDV_PLANS))
+@pytest.mark.parametrize("k,m", GRANITE_8B)
+@pytest.mark.parametrize("rows", [16, 64])              # decode, chunk
+def test_sdv_default_tiles_compile(compile_tpu, monkeypatch, datapath, k, m,
+                                   rows):
+    """``packed_matmul`` with no blocks given: the widened group block
+    and its K block fit the chip's scoped VMEM."""
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    plan = _sdv_plan(datapath)
+    hlo = compile_tpu(
+        lambda x, w: ops.packed_matmul(x, w, plan=plan, m=m),
+        ((rows, k), jnp.int32), (_word_shape(plan, k, m), jnp.int32))
     assert "tpu_custom_call" in hlo
 
 
