@@ -32,7 +32,7 @@ SHAPES = {
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                     # dense | moe | encdec | hybrid | ssm | vlm
+    family: str                     # dense | moe | encdec | hybrid | ssm | vlm | moe_hybrid
     n_layers: int
     d_model: int
     n_heads: int
@@ -44,10 +44,18 @@ class ArchConfig:
     qkv_bias: bool = False
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
+    use_rope: bool = True           # False: NoPE attention
+    attn_scale: Optional[float] = None   # None: 1 / sqrt(head_dim)
+    rms_eps: float = 1e-6
+    # --- Granite residual scaling (1.0: off) ---
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0     # logits are divided by it
     # --- MoE ---
     n_experts: int = 0
     top_k: int = 0
     shared_expert: bool = False
+    shared_d_ff: int = 0        # shared expert width (0: d_ff)
     moe_every: int = 1          # llama4: MoE FFN on every 2nd layer
     # --- hybrid (RG-LRU) ---
     window: Optional[int] = None
@@ -57,6 +65,9 @@ class ArchConfig:
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_groups: int = 1
+    # --- per-layer mixer pattern (moe_hybrid): "mamba" | "attention"
+    # per layer; the layers repeat its shortest period ---
+    layer_types: Tuple[str, ...] = ()
     # --- enc-dec ---
     n_enc_layers: int = 0
     n_dec_layers: int = 0
@@ -98,6 +109,16 @@ class ArchConfig:
     def dtype(self):
         return jnp.bfloat16
 
+    @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The shortest prefix of ``layer_types`` whose repeats give
+        every layer (one period of the mixer pattern)."""
+        t, n = self.layer_types, len(self.layer_types)
+        for p in range(1, n + 1):
+            if n % p == 0 and t == t[:p] * (n // p):
+                return t[:p]
+        return t
+
     def shape_supported(self, shape: ShapeCell) -> Tuple[bool, str]:
         if shape.name == "long_500k" and not self.subquadratic:
             return False, ("full attention at 524288 context is not "
@@ -108,11 +129,20 @@ class ArchConfig:
 
     def reduced(self) -> "ArchConfig":
         """Same-family tiny config for CPU smoke tests."""
+        # a mixer pattern keeps both kinds: (mamba, attention, mamba)
+        types = ("mamba", "attention", "mamba") if self.layer_types else ()
+        if types:
+            n_layers = len(types)
+        elif self.family == "hybrid":
+            # one full (rec, rec, attn) group + 2 tail layers
+            n_layers = 5
+        else:
+            n_layers = min(self.n_layers, 2)
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            # hybrid keeps one full (rec, rec, attn) group + 2 tail layers
-            n_layers=5 if self.family == "hybrid" else min(self.n_layers, 2),
+            n_layers=n_layers,
+            layer_types=types,
             n_enc_layers=min(self.n_enc_layers, 2),
             n_dec_layers=min(self.n_dec_layers, 2),
             d_model=128,
@@ -120,10 +150,12 @@ class ArchConfig:
             n_kv=min(self.n_kv, 2) if self.n_kv else 0,
             head_dim=32,
             d_ff=256,
+            shared_d_ff=192 if self.shared_d_ff else 0,
             d_rnn=128 if self.d_rnn else 0,
             d_inner=256 if self.d_inner else 0,
             ssm_state=32 if self.ssm_state else 0,
             ssm_heads=4 if self.ssm_heads else 0,
+            attn_scale=1.0 / 32 if self.attn_scale else None,
             vocab=512,
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
@@ -149,6 +181,8 @@ def param_count(cfg: ArchConfig) -> int:
             + (di + 2 * gn) * 4
         return emb // 2 * (1 if cfg.tie_embeddings else 2) \
             + cfg.n_layers * blk
+    if cfg.family == "moe_hybrid":
+        return emb + _hybrid_moe_blocks(cfg, cfg.n_experts)
     if cfg.family == "moe":
         n_moe = cfg.n_layers // cfg.moe_every
         n_dense = cfg.n_layers - n_moe
@@ -172,8 +206,25 @@ def param_count(cfg: ArchConfig) -> int:
     return emb + cfg.n_layers * (attn + 3 * d * ff)
 
 
+def _hybrid_moe_blocks(cfg: ArchConfig, experts: int) -> int:
+    """Projection parameters of a moe_hybrid model's layers: each
+    layer's mixer (Mamba2 or attention), ``experts`` routed experts and
+    the shared MLP."""
+    d, hd = cfg.d_model, cfg.hd
+    attn = d * hd * (cfg.n_heads + 2 * cfg.n_kv) + cfg.n_heads * hd * d
+    gn = cfg.ssm_groups * cfg.ssm_state
+    mamba = d * (2 * cfg.d_inner + 2 * gn + cfg.ssm_heads) \
+        + cfg.d_inner * d
+    ffn = 3 * d * (experts * cfg.d_ff + (cfg.shared_d_ff or cfg.d_ff))
+    return sum((attn if t == "attention" else mamba) + ffn
+               for t in cfg.layer_types)
+
+
 def active_param_count(cfg: ArchConfig) -> int:
     """Active parameters per token (MoE: top_k experts only)."""
+    if cfg.family == "moe_hybrid":
+        emb = cfg.vocab * cfg.d_model * (1 if cfg.tie_embeddings else 2)
+        return emb + _hybrid_moe_blocks(cfg, cfg.top_k)
     if cfg.family != "moe":
         return param_count(cfg)
     d, ff = cfg.d_model, cfg.d_ff

@@ -1,4 +1,4 @@
-"""The 10 assigned architectures (+ UltraNet, the paper's own model).
+"""The assigned architectures (+ UltraNet, the paper's own model).
 
 Each entry matches the assigned config cell verbatim; deviations forced
 by published-architecture details are commented inline and recorded in
@@ -74,10 +74,25 @@ MAMBA2_130M = ArchConfig(
     vocab=50280, d_inner=1536, ssm_state=128, ssm_heads=24,
     ssm_groups=1, tie_embeddings=True, subquadratic=True)
 
+GRANITE_4H_SMALL = ArchConfig(
+    # granitemoehybrid: 40 layers, Mamba2 everywhere but layers 5, 15,
+    # 25 and 35 (GQA attention with no position embedding, scaled by
+    # attention_multiplier 1/128); every layer's FFN is 72 experts,
+    # top-10, width 768, beside a shared MLP of width 1536
+    name="granite-4.0-h-small", family="moe_hybrid",
+    n_layers=40, d_model=4096, n_heads=32, n_kv=8, head_dim=128,
+    d_ff=768, vocab=100352, n_experts=72, top_k=10, shared_expert=True,
+    shared_d_ff=1536, d_inner=8192, ssm_state=128, ssm_heads=128,
+    ssm_groups=1,
+    layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+    use_rope=False, attn_scale=0.0078125, rms_eps=1e-5,
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    logits_scaling=16.0, tie_embeddings=True, fsdp=True)
+
 ARCHS = {a.name: a for a in [
     QWEN25_32B, GEMMA_2B, GRANITE_8B, TINYLLAMA_11B, PHI35_MOE,
     LLAMA4_MAVERICK, SEAMLESS_M4T, RECURRENTGEMMA_2B, LLAVA_NEXT_MISTRAL,
-    MAMBA2_130M,
+    MAMBA2_130M, GRANITE_4H_SMALL,
 ]}
 
 # short aliases for --arch
@@ -94,6 +109,7 @@ ALIASES = {
     "recurrentgemma-2b": "recurrentgemma-2b",
     "llava-next-mistral-7b": "llava-next-mistral-7b",
     "mamba2-130m": "mamba2-130m",
+    "granite-4.0-h-small": "granite-4.0-h-small",
 }
 
 

@@ -1,5 +1,6 @@
 """Layer library: norms, projections, rotary attention (chunked /
-flash-style), gated MLPs, and capacity-based MoE.
+flash-style), gated MLPs, and MoE (capacity dispatch for training,
+dropless grouped matmuls for serving).
 
 All layers are functional: ``*_init(ini, ...) -> param pytree (P
 leaves)`` and ``*_apply(params, x, ...) -> y`` with plain jnp values.
@@ -109,6 +110,13 @@ class AttnConfig:
     softcap: Optional[float] = None
     use_rope: bool = True
     free_qkv_sharding: bool = False  # skip explicit q/k/v constraints
+    scale: Optional[float] = None    # score scale (None: 1 / sqrt(hd))
+
+
+def _scaled(s, cfg: AttnConfig, hd: int):
+    """Attention scores times the configured scale; the default
+    divides by sqrt(hd) exactly as before the scale existed."""
+    return s / math.sqrt(hd) if cfg.scale is None else s * cfg.scale
 
 
 def attention_init(ini: Init, cfg: AttnConfig):
@@ -125,7 +133,8 @@ def attention_init(ini: Init, cfg: AttnConfig):
 
 
 def _stream_attend(q, k, v, *, q_start: int, causal: bool,
-                   window: Optional[int], chunk: int, softcap=None):
+                   window: Optional[int], chunk: int, softcap=None,
+                   scale: Optional[float] = None):
     """Two-level streaming softmax attention (flash-style, pure JAX).
 
     q [B, Sq, KV, R, D] (R = heads per kv group), k/v [B, Sk, KV, D].
@@ -139,7 +148,7 @@ def _stream_attend(q, k, v, *, q_start: int, causal: bool,
     """
     b, sq, kvh, r, d = q.shape
     sk = k.shape[1]
-    scalef = 1.0 / math.sqrt(d)
+    scalef = 1.0 / math.sqrt(d) if scale is None else scale
     nkv = -(-sk // chunk)
     kp = jnp.pad(k, ((0, 0), (0, nkv * chunk - sk), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, nkv * chunk - sk), (0, 0), (0, 0)))
@@ -202,14 +211,15 @@ def _stream_attend(q, k, v, *, q_start: int, causal: bool,
 
 
 def _stream_attend_diff(q, k, v, *, q_start: int, causal: bool,
-                        window: Optional[int], chunk: int, softcap=None):
+                        window: Optional[int], chunk: int, softcap=None,
+                        scale: Optional[float] = None):
     """Differentiable variant: the query-chunk loop is a *python* loop,
     so every KV range is static and the inner walk is a reverse-mode-
     friendly ``lax.scan`` — while-loops (dynamic fori bounds) cannot be
     transposed by JAX.  Same math, same causal-FLOPs saving."""
     b, sq, kvh, r, d = q.shape
     sk = k.shape[1]
-    scalef = 1.0 / math.sqrt(d)
+    scalef = 1.0 / math.sqrt(d) if scale is None else scale
     nkv = -(-sk // chunk)
     kp = jnp.pad(k, ((0, 0), (0, nkv * chunk - sk), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, nkv * chunk - sk), (0, 0), (0, 0)))
@@ -310,7 +320,7 @@ def attention_apply(params, cfg: AttnConfig, x, *, positions,
     attend = _stream_attend_diff if differentiable else _stream_attend
     out = attend(qg, k, v, q_start=q_start, causal=causal,
                  window=cfg.window, chunk=min(chunk, max(s, 16)),
-                 softcap=cfg.softcap)
+                 softcap=cfg.softcap, scale=cfg.scale)
     out = out.reshape(b, s, h * hd)
     return dense_apply(params["wo"], out), (k, v)
 
@@ -376,9 +386,9 @@ def decode_attention(params, cfg: AttnConfig, x, *, cache_k, cache_v,
     valid = kpos[None, :] <= idx[:, None]
     if cfg.window is not None:
         valid = valid & (kpos[None, :] > idx[:, None] - cfg.window)
-    s = jnp.einsum("bgrd,bkgd->bgrk",
-                   q.reshape(b, g, r, hd).astype(jnp.float32),
-                   kc_f) / math.sqrt(hd)
+    s = _scaled(jnp.einsum("bgrd,bkgd->bgrk",
+                           q.reshape(b, g, r, hd).astype(jnp.float32),
+                           kc_f), cfg, hd)
     if cfg.softcap is not None:
         s = jnp.tanh(s / cfg.softcap) * cfg.softcap
     s = jnp.where(valid[:, None, None, :], s, -1e30)
@@ -438,9 +448,9 @@ def prefill_attention(params, cfg: AttnConfig, x, *, cache_k, cache_v,
     valid = kpos[None, None, :] <= pos[:, :, None]                 # [B, C, S]
     if cfg.window is not None:
         valid = valid & (kpos[None, None, :] > pos[:, :, None] - cfg.window)
-    s = jnp.einsum("bcgrd,bsgd->bgrcs",
-                   q.reshape(b, c, g, r, hd).astype(jnp.float32),
-                   kc_f) / math.sqrt(hd)
+    s = _scaled(jnp.einsum("bcgrd,bsgd->bgrcs",
+                           q.reshape(b, c, g, r, hd).astype(jnp.float32),
+                           kc_f), cfg, hd)
     if cfg.softcap is not None:
         s = jnp.tanh(s / cfg.softcap) * cfg.softcap
     s = jnp.where(valid[:, None, None, :, :], s, -1e30)
@@ -480,7 +490,8 @@ def mlp_apply(params, x, *, act: str = "swiglu"):
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts (token-choice top-k, capacity dispatch, EP-sharded)
+# Mixture of Experts (token-choice top-k): capacity dispatch (EP-sharded,
+# training) and dropless grouped matmuls (serving)
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -492,6 +503,7 @@ class MoEConfig:
     capacity_factor: float = 1.25
     shared_expert: bool = False      # llama4-style always-on expert
     act: str = "swiglu"
+    shared_d_ff: int = 0             # shared expert width (0: d_ff)
 
 
 def moe_init(ini: Init, cfg: MoEConfig):
@@ -506,22 +518,36 @@ def moe_init(ini: Init, cfg: MoEConfig):
                          std=1.0 / math.sqrt(f)),
     }
     if cfg.shared_expert:
-        p["shared"] = mlp_init(ini, d, f)
+        p["shared"] = mlp_init(ini, d, cfg.shared_d_ff or f)
     return p
 
 
+def _route(params, cfg: MoEConfig, xt):
+    """Token-choice routing of xt [T, d]: the top-k experts of each
+    token and their softmax weights renormalized over the k (the same
+    function as a softmax over the top-k logits).  [T, k] each."""
+    logits = dense_apply(params["router"],
+                         xt.astype(jnp.float32))             # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)           # [T, k]
+    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return top_p, top_e
+
+
+def _act(cfg: MoEConfig, gate):
+    return jax.nn.silu(gate) if cfg.act == "swiglu" \
+        else jax.nn.gelu(gate, approximate=True)
+
+
 def moe_apply(params, cfg: MoEConfig, x):
-    """x [B, S, d] -> [B, S, d].  Capacity-dropped token-choice routing."""
+    """x [B, S, d] -> [B, S, d].  Capacity-dropped token-choice routing
+    (the training path; serving runs ``moe_dropless``)."""
     b, s, d = x.shape
     t = b * s
     e, k = cfg.n_experts, cfg.top_k
     cap = max(1, int(math.ceil(t * k * cfg.capacity_factor / e)))
     xt = x.reshape(t, d)
-    logits = dense_apply(params["router"],
-                         xt.astype(jnp.float32))             # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)                   # [T, k]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_e = _route(params, cfg, xt)                   # [T, k]
 
     # slot position of each (token, choice) within its expert
     flat_e = top_e.reshape(-1)                               # [T*k]
@@ -544,8 +570,7 @@ def moe_apply(params, cfg: MoEConfig, x):
     # expert FFNs: [E, C, d] x [E, d, f]
     gate = jnp.einsum("ecd,edf->ecf", buf, mat(params["wi_gate"], x.dtype))
     up = jnp.einsum("ecd,edf->ecf", buf, mat(params["wi_up"], x.dtype))
-    a = jax.nn.silu(gate) if cfg.act == "swiglu" \
-        else jax.nn.gelu(gate, approximate=True)
+    a = _act(cfg, gate)
     out_e = jnp.einsum("ecf,efd->ecd", a * up,
                        mat(params["wo"], x.dtype))           # [E, C, d]
 
@@ -559,6 +584,47 @@ def moe_apply(params, cfg: MoEConfig, x):
         y = y + mlp_apply(params["shared"], x, act=cfg.act)
     # auxiliary load-balance loss (returned via side channel by caller)
     return y
+
+
+#: the routing statistics ``moe_dropless`` returns per call, in order
+MOE_STATS = ("rows", "peak_rows", "experts_touched")
+
+
+def moe_dropless(params, cfg: MoEConfig, x):
+    """x [B, S, d] -> (y [B, S, d], stats int32 [3]).  Every routed row
+    is computed: the T x top_k (token, expert) rows are sorted by
+    expert and each expert's FFN multiplies its own rows through one
+    grouped matmul per projection (``kernels/moe_gmm``), so a token's
+    output never depends on which other tokens share its call.
+    ``stats`` are ``MOE_STATS``: the rows routed, the rows of the
+    busiest expert and the experts that got at least one row."""
+    from repro.kernels.moe_gmm import moe_gmm
+    from repro.kernels.ops import _on_cpu
+    b, s, d = x.shape
+    t = b * s
+    e, k = cfg.n_experts, cfg.top_k
+    xt = x.reshape(t, d)
+    top_p, top_e = _route(params, cfg, xt)                   # [T, k]
+    flat_e = top_e.reshape(-1)                               # [T*k]
+    order = jnp.argsort(flat_e, stable=True)                 # by expert
+    sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+    rows = xt[order // k]                                    # [T*k, d]
+    interpret = _on_cpu()
+    with jax.named_scope("moe.experts"):
+        gate = moe_gmm(rows, mat(params["wi_gate"], x.dtype), sizes,
+                       interpret=interpret)
+        up = moe_gmm(rows, mat(params["wi_up"], x.dtype), sizes,
+                     interpret=interpret)
+        out = moe_gmm(_act(cfg, gate) * up, mat(params["wo"], x.dtype),
+                      sizes, interpret=interpret)            # [T*k, d]
+    back = jnp.zeros_like(out).at[order].set(out)            # unsort
+    w = top_p.reshape(-1)[:, None].astype(x.dtype)
+    y = (back * w).reshape(t, k, d).sum(axis=1).reshape(b, s, d)
+    if cfg.shared_expert:
+        y = y + mlp_apply(params["shared"], x, act=cfg.act)
+    stats = jnp.stack([jnp.asarray(t * k, jnp.int32), sizes.max(),
+                       (sizes > 0).sum().astype(jnp.int32)])
+    return y, stats
 
 
 def moe_aux_loss(params, cfg: MoEConfig, x):

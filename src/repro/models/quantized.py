@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +100,56 @@ def pack_linear(kernel: jnp.ndarray, bits: int) -> PackedLinear:
         words = words | (field << (i * bits))
     return PackedLinear(words=words, scale=scale.astype(jnp.float32),
                         bits=bits, d_out=d_out)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerStream:
+    """A parameter tree whose scanned layers are made one at a time.
+
+    ``shared`` holds every top-level entry but ``blocks``;
+    ``make(name, i)`` makes entry ``name`` of ``blocks`` for scan step
+    ``i`` (its leaves without the leading scan axis); ``names`` lists
+    the entries and ``depth`` the scan steps.  ``serve_params`` packs a
+    stream one layer at a time, so the float weights of one layer at
+    most exist at once, and gives bitwise the tree it gives for the
+    whole stacked tree."""
+    shared: Dict[str, Any]
+    make: Callable[[str, int], Any]
+    names: Tuple[str, ...]
+    depth: int
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_leaves(stack, i, layer):
+    return jax.tree_util.tree_map(lambda a, b: a.at[i].set(b), stack, layer)
+
+
+def _serve_stream(stream: LayerStream, bits: int, min_size: int,
+                  compute: str) -> Dict[str, Any]:
+    """``serve_params`` of a ``LayerStream``: the shared entries packed
+    as a whole, then each layer made, packed and written in place into
+    its stacked result (memory packing only: per output channel, so a
+    layer packs alone exactly as it packs in its stack)."""
+    if compute != "memory":
+        raise ValueError("a LayerStream packs on the memory route only")
+    out = serve_params(stream.shared, bits=bits, min_size=min_size)
+    # a stacked leaf packs where depth x its size reaches min_size
+    layer_min = -(-min_size // stream.depth)
+    blocks = {}
+    for name in stream.names:
+        stack = None
+        for i in range(stream.depth):
+            layer = serve_params(stream.make(name, i), bits=bits,
+                                 min_size=layer_min)
+            if stack is None:
+                stack = jax.tree_util.tree_map(
+                    lambda a: jnp.zeros((stream.depth,) + a.shape, a.dtype),
+                    layer)
+            stack = _put_leaves(stack, i, layer)
+            del layer
+        blocks[name] = stack
+    out["blocks"] = blocks
+    return out
 
 
 def default_sdv_plan(bits: int, act_bits: int = 8) -> SDVPlan:
@@ -363,9 +413,14 @@ def serve_params(params: Any, bits: int = 4,
     dimensions matmul layers for (default ``PLANNER_DECODE_ROWS``) —
     the serving engine passes each bucket's batch size so per-bucket
     plan resolution sees the shape it will actually run.
+
+    ``params`` may be a ``LayerStream`` (memory packing only): its
+    layers are then made and packed one at a time.
     """
     if compute not in ("memory", "sdv"):
         raise ValueError(f"unknown packed compute mode {compute!r}")
+    if isinstance(params, LayerStream):
+        return _serve_stream(params, bits, min_size, compute)
     if rows is None:
         rows = PLANNER_DECODE_ROWS
     if plan_policy not in ("default", "auto", "cache"):

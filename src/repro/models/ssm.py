@@ -70,6 +70,7 @@ class SSMConfig:
     n_groups: int = 1
     d_conv: int = 4
     chunk: int = 256
+    norm_eps: float = 1e-6          # the gated RMSNorm's eps
 
     @property
     def head_dim(self) -> int:
@@ -159,8 +160,14 @@ def _ssd_chunked(x, dt, a, b_in, c_in, cfg: SSMConfig,
 
 
 def ssm_apply(params, cfg: SSMConfig, x, *, conv_state=None, ssm_state=None,
-              decode: bool = False):
-    """Mamba2 block. x [B, S, d_model] -> (y, (conv_state, ssm_state))."""
+              decode: bool = False, n_valid=None):
+    """Mamba2 block. x [B, S, d_model] -> (y, (conv_state, ssm_state)).
+
+    ``n_valid`` [B] int32 (optional, chunked prefill from
+    ``conv_state``/``ssm_state``): only the first n_valid positions of
+    each row are real.  The rest take dt = 0, so they leave the SSM
+    state as the last real position left it, and the conv state is
+    taken from the last real positions; their outputs are never read."""
     bsz, s, _ = x.shape
     di, h, p = cfg.d_inner, cfg.n_heads, cfg.head_dim
     gn = cfg.n_groups * cfg.d_state
@@ -169,8 +176,17 @@ def ssm_apply(params, cfg: SSMConfig, x, *, conv_state=None, ssm_state=None,
     bc = dense_apply(params["in_bc"], x)
     dt = dense_apply(params["in_dt"], x)
     conv_in = jnp.concatenate([xin, bc], axis=-1)
+    conv_prev = conv_state
     conv_out, conv_state = short_conv_apply(params["conv"], conv_in,
                                             state=conv_state)
+    if n_valid is not None:
+        # the inputs of the d_conv - 1 positions before the first padded one
+        taps1 = conv_state.shape[1]
+        hist = jnp.concatenate([conv_prev.astype(conv_in.dtype), conv_in],
+                               axis=1)
+        conv_state = jax.vmap(
+            lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, taps1)
+        )(hist, jnp.asarray(n_valid, jnp.int32)).astype(conv_state.dtype)
     conv_out = jax.nn.silu(conv_out)
     xs, bs, cs = jnp.split(conv_out, [di, di + gn], axis=-1)
     xh = xs.reshape(bsz, s, h, p)
@@ -178,6 +194,9 @@ def ssm_apply(params, cfg: SSMConfig, x, *, conv_state=None, ssm_state=None,
     ch = cs.reshape(bsz, s, cfg.n_groups, cfg.d_state)
     dtp = jax.nn.softplus(dt.astype(jnp.float32)
                           + params["dt_bias"][None, None, :])
+    if n_valid is not None:
+        real = jnp.arange(s)[None, :] < jnp.asarray(n_valid)[:, None]
+        dtp = jnp.where(real[..., None], dtp, 0.0)
     a = -jnp.exp(params["a_log"])                            # [H] negative
 
     if decode:
@@ -199,5 +218,5 @@ def ssm_apply(params, cfg: SSMConfig, x, *, conv_state=None, ssm_state=None,
     y = y + params["d_skip"][None, None, :, None] \
         * xh.astype(jnp.float32)
     y = y.reshape(bsz, s, di).astype(x.dtype)
-    y = rmsnorm_apply(params["norm"], y * jax.nn.silu(z))
+    y = rmsnorm_apply(params["norm"], y * jax.nn.silu(z), eps=cfg.norm_eps)
     return dense_apply(params["out_proj"], y), (conv_state, ssm_state)

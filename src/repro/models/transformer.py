@@ -1,7 +1,9 @@
 """Model assembly for all assigned architecture families.
 
 Families: dense / moe / vlm (decoder LM), encdec (encoder-decoder),
-hybrid (RG-LRU + local attention, Griffin 1:2 pattern), ssm (Mamba2).
+hybrid (RG-LRU + local attention, Griffin 1:2 pattern), ssm (Mamba2),
+moe_hybrid (GraniteMoeHybrid: a per-layer pattern of Mamba2 and NoPE
+attention mixers, every layer's FFN a dropless MoE beside a shared MLP).
 
 Layers are stacked and driven by ``lax.scan`` (MaxText-style) so the
 64-layer dry-runs stay compact in HLO; remat wraps each block.  Caches
@@ -55,20 +57,33 @@ def _attn_cfg(cfg: ArchConfig, *, window=None, use_rope=True) -> L.AttnConfig:
     return L.AttnConfig(
         d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
         head_dim=cfg.hd, qkv_bias=cfg.qkv_bias, rope_theta=cfg.rope_theta,
-        window=window, use_rope=use_rope,
-        free_qkv_sharding=cfg.free_qkv_sharding)
+        window=window, use_rope=use_rope and cfg.use_rope,
+        free_qkv_sharding=cfg.free_qkv_sharding, scale=cfg.attn_scale)
 
 
 def _moe_cfg(cfg: ArchConfig) -> L.MoEConfig:
     return L.MoEConfig(d_model=cfg.d_model, d_ff=cfg.d_ff,
                        n_experts=cfg.n_experts, top_k=cfg.top_k,
-                       shared_expert=cfg.shared_expert, act=cfg.act)
+                       shared_expert=cfg.shared_expert, act=cfg.act,
+                       shared_d_ff=cfg.shared_d_ff)
 
 
 def _ssm_cfg(cfg: ArchConfig) -> S.SSMConfig:
     return S.SSMConfig(d_model=cfg.d_model, d_inner=cfg.d_inner,
                        n_heads=cfg.ssm_heads, d_state=cfg.ssm_state,
-                       n_groups=cfg.ssm_groups)
+                       n_groups=cfg.ssm_groups, norm_eps=cfg.rms_eps)
+
+
+#: families whose per-slot state takes ``decode_step``'s advance mask
+#: and chunked prefill (``prefill_step``/``prefill_slot``)
+SLOT_STATE_FAMILIES = ("dense", "moe", "vlm", "moe_hybrid")
+
+
+def slot_state(cfg: ArchConfig) -> bool:
+    """Whether the model's per-slot state (KV rows, SSM and conv
+    state) takes the advance mask and chunked prefill; the others
+    replay prompts one token per ``decode_step``."""
+    return cfg.family in SLOT_STATE_FAMILIES
 
 
 def _rg_cfg(cfg: ArchConfig) -> R.RGLRUConfig:
@@ -114,6 +129,19 @@ def _hybrid_group_init(ini: Init, cfg: ArchConfig):
     }
 
 
+def _hybrid_moe_layer_init(ini: Init, cfg: ArchConfig, kind: str):
+    """One GraniteMoeHybrid layer: a Mamba2 or attention mixer, then
+    the MoE FFN (with its shared MLP), each behind an RMSNorm."""
+    p = {"ln_mix": L.rmsnorm_init(ini, cfg.d_model),
+         "ln_mlp": L.rmsnorm_init(ini, cfg.d_model),
+         "moe": L.moe_init(ini, _moe_cfg(cfg))}
+    if kind == "mamba":
+        p["mamba"] = S.ssm_init(ini, _ssm_cfg(cfg))
+    else:
+        p["attn"] = L.attention_init(ini, _attn_cfg(cfg))
+    return p
+
+
 def _ssm_block_init(ini: Init, cfg: ArchConfig):
     return {
         "ln": L.rmsnorm_init(ini, cfg.d_model),
@@ -149,6 +177,13 @@ def init_params(cfg: ArchConfig, rules: Rules,
     elif cfg.family == "ssm":
         sini = StackedInit(ini, cfg.n_layers)
         p["blocks"] = _ssm_block_init(sini, cfg)
+    elif cfg.family == "moe_hybrid":
+        # scanned over periods of the mixer pattern; "l<j>" is the
+        # period's layer j, stacked over periods
+        period = cfg.layer_period
+        sini = StackedInit(ini, cfg.n_layers // len(period))
+        p["blocks"] = {f"l{j}": _hybrid_moe_layer_init(sini, cfg, kind)
+                       for j, kind in enumerate(period)}
     elif cfg.family == "hybrid":
         n_groups = cfg.n_layers // 3
         n_tail = cfg.n_layers - 3 * n_groups      # trailing rec layers
@@ -237,8 +272,10 @@ def _decoder_block_apply(bp, cfg: ArchConfig, x, positions, *,
                                  differentiable=diff)
         x = x + h
     y = L.rmsnorm_apply(bp["ln_mlp"], x)
-    if cfg.family == "moe":
+    if cfg.family == "moe" and diff:        # training: capacity dispatch
         x = x + L.moe_apply(bp["moe"], _moe_cfg(cfg), y)
+    elif cfg.family == "moe":               # inference: dropless
+        x = x + L.moe_dropless(bp["moe"], _moe_cfg(cfg), y)[0]
     else:
         x = x + L.mlp_apply(bp["mlp"], y, act=cfg.act)
     return x
@@ -277,6 +314,135 @@ def _hybrid_group_apply(gp, cfg: ArchConfig, x, positions, *, states=None,
     return x, new_states
 
 
+def _hybrid_moe_layer(bp, cfg: ArchConfig, x, mixer, *, dropless=True):
+    """One GraniteMoeHybrid layer around ``mixer`` (normed input ->
+    (output, new mixer state)):
+
+        h = x + rm * mixer(RMSNorm(x));  y = h + rm * MoE(RMSNorm(h))
+
+    with rm the residual multiplier and MoE the routed experts plus
+    the shared MLP.  ``dropless=False`` is the training path (capacity
+    dispatch).  Returns (y, mixer state, routing stats or None)."""
+    rm, eps = cfg.residual_multiplier, cfg.rms_eps
+    h, state = mixer(L.rmsnorm_apply(bp["ln_mix"], x, eps=eps))
+    x = x + h * rm
+    z = L.rmsnorm_apply(bp["ln_mlp"], x, eps=eps)
+    if dropless:
+        y, stats = L.moe_dropless(bp["moe"], _moe_cfg(cfg), z)
+    else:
+        y, stats = L.moe_apply(bp["moe"], _moe_cfg(cfg), z), None
+    return x + y * rm, state, stats
+
+
+def _hybrid_moe_period(bp, cfg: ArchConfig, x, positions, *, diff=True):
+    """Full-sequence forward of one period of the mixer pattern (no
+    cache): the training path when ``diff``, else dropless."""
+    acfg, scfg = _attn_cfg(cfg), _ssm_cfg(cfg)
+    for j, kind in enumerate(cfg.layer_period):
+        if kind == "mamba":
+            def mixer(a, _p=bp[f"l{j}"]["mamba"]):
+                return S.ssm_apply(_p, scfg, a)
+        else:
+            def mixer(a, _p=bp[f"l{j}"]["attn"]):
+                return L.attention_apply(
+                    _p, acfg, a, positions=positions, causal=True,
+                    chunk=cfg.attn_chunk, differentiable=diff)
+        x, _, _ = _hybrid_moe_layer(bp[f"l{j}"], cfg, x, mixer,
+                                    dropless=not diff)
+    return x
+
+
+def _hybrid_moe_cached(cfg: ArchConfig, params, cache, x, *, decode,
+                       advance=None, n_valid=None):
+    """The period scan of ``decode_step`` (``decode``: x [B, 1, d],
+    ``advance`` [B] or None) and of chunked prefill (x [B, C, d],
+    ``n_valid`` [B]) against the cache of ``init_cache``: attention
+    layers keep int8 KV rows, Mamba2 layers conv and SSM state, all
+    laid out (layers, B, ...).  A slot whose advance is 0 (decode) or
+    whose n_valid is 0 (prefill) keeps every state bit-identical.
+    Returns (x, new cache leaves without ``index``, stats [L, 3])."""
+    period = cfg.layer_period
+    n_per = cfg.n_layers // len(period)
+    mam = [j for j, t in enumerate(period) if t == "mamba"]
+    att = [j for j, t in enumerate(period) if t != "mamba"]
+    b = x.shape[0]
+    index = jnp.broadcast_to(jnp.asarray(cache["index"], jnp.int32), (b,))
+    acfg, scfg = _attn_cfg(cfg), _ssm_cfg(cfg)
+    if decode:
+        keep = None if advance is None else jnp.asarray(advance) > 0
+        wmask = keep
+    else:
+        keep = jnp.asarray(n_valid, jnp.int32) > 0
+
+    def per_period(name, n):
+        if not n:
+            return None
+        leaf = cache[name]
+        return leaf.reshape((n_per, n) + leaf.shape[1:])
+
+    kv_names = tuple(nm for nm in ("k", "v", "k_scale", "v_scale")
+                     if nm in cache)
+    kv = tuple(per_period(nm, len(att)) for nm in kv_names)
+    conv, ssm = per_period("conv", len(mam)), per_period("ssm", len(mam))
+
+    def hold(new, old):
+        if keep is None:
+            return new
+        return jnp.where(keep.reshape((b,) + (1,) * (new.ndim - 1)),
+                         new, old)
+
+    def body(xc, sl):
+        bp, kvs, cv, st = sl
+        new_kv = [[] for _ in kv_names]
+        new_cv, new_st, stats = [], [], []
+        for j, kind in enumerate(period):
+            lp = bp[f"l{j}"]
+            if kind == "mamba":
+                i = mam.index(j)
+
+                def mixer(a, _p=lp["mamba"], _i=i):
+                    h, (c1, s1) = S.ssm_apply(
+                        _p, scfg, a, conv_state=cv[_i], ssm_state=st[_i],
+                        decode=decode, n_valid=None if decode else n_valid)
+                    return h, (hold(c1, cv[_i]), hold(s1, st[_i]))
+            else:
+                i = att.index(j)
+                kvi = [t[i] for t in kvs]
+                ck, cvv = kvi[0], kvi[1]
+                ks, vs = kvi[2:] if len(kvi) == 4 else (None, None)
+
+                def mixer(a, _p=lp["attn"], ck=ck, cvv=cvv, ks=ks, vs=vs):
+                    if decode:
+                        out = L.decode_attention(
+                            _p, acfg, a, cache_k=ck, cache_v=cvv,
+                            cache_index=index, cache_k_scale=ks,
+                            cache_v_scale=vs, write_mask=wmask)
+                    else:
+                        out = L.prefill_attention(
+                            _p, acfg, a, cache_k=ck, cache_v=cvv,
+                            cache_index=index, n_valid=n_valid,
+                            cache_k_scale=ks, cache_v_scale=vs)
+                    return out[0], out[1:]
+            xc, state, st_j = _hybrid_moe_layer(lp, cfg, xc, mixer)
+            stats.append(st_j)
+            if kind == "mamba":
+                new_cv.append(state[0])
+                new_st.append(state[1])
+            else:
+                for lst, t in zip(new_kv, state):
+                    lst.append(t)
+        stack = lambda ts: jnp.stack(ts) if ts else None  # noqa: E731
+        return xc, (tuple(stack(t) for t in new_kv), stack(new_cv),
+                    stack(new_st), jnp.stack(stats))
+
+    x, (nkv, ncv, nst, stats) = _layer_loop(
+        cfg, body, x, (params["blocks"], kv, conv, ssm), n_per)
+    new = {nm: t.reshape(cache[nm].shape) for nm, t in
+           zip(kv_names + ("conv", "ssm"), nkv + (ncv, nst))
+           if t is not None}
+    return x, new, stats.reshape(cfg.n_layers, len(L.MOE_STATS))
+
+
 # ---------------------------------------------------------------------------
 # full forward (train / prefill)
 # ---------------------------------------------------------------------------
@@ -285,12 +451,14 @@ def _embed(cfg: ArchConfig, params, tokens):
     x = params["embed"][tokens]
     if cfg.act == "geglu":                 # gemma family scales embeddings
         x = x * math.sqrt(cfg.d_model)
+    if cfg.embedding_multiplier != 1.0:    # granite
+        x = x * cfg.embedding_multiplier
     return shard_ctx.constrain(x.astype(cfg.dtype), "batch", None, None)
 
 
 def _finish(cfg: ArchConfig, params, x, mode: str):
     if mode == "hidden":
-        return L.rmsnorm_apply(params["ln_f"], x)
+        return L.rmsnorm_apply(params["ln_f"], x, eps=cfg.rms_eps)
     if mode == "last_logits":
         return _unembed(cfg, params, x[:, -1:, :])
     return _unembed(cfg, params, x)
@@ -304,20 +472,25 @@ def unembed_hidden(cfg: ArchConfig, params, h):
         logits = params["lm_head"].qat_apply(h)   # QAT STE (train/qat)
     else:
         logits = h @ L.mat(params["lm_head"], h.dtype)
-    return shard_ctx.constrain(logits.astype(jnp.float32),
-                               "batch", None, "tp")
+    return _scale_logits(cfg, logits)
+
+
+def _scale_logits(cfg: ArchConfig, logits):
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:          # granite divides its logits
+        logits = logits / cfg.logits_scaling
+    return shard_ctx.constrain(logits, "batch", None, "tp")
 
 
 def _unembed(cfg: ArchConfig, params, x):
-    x = L.rmsnorm_apply(params["ln_f"], x)
+    x = L.rmsnorm_apply(params["ln_f"], x, eps=cfg.rms_eps)
     if cfg.tie_embeddings:
         logits = x @ params["embed"].astype(x.dtype).T
     elif hasattr(params["lm_head"], "qat_apply"):
         logits = params["lm_head"].qat_apply(x)   # QAT STE (train/qat)
     else:
         logits = x @ L.mat(params["lm_head"], x.dtype)
-    return shard_ctx.constrain(logits.astype(jnp.float32),
-                               "batch", None, "tp")
+    return _scale_logits(cfg, logits)
 
 
 def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray],
@@ -325,7 +498,9 @@ def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray],
             mode: str = "logits"):
     """mode: "logits" (full [B,S,V]), "hidden" (post-ln_f states, for
     the memory-safe chunked loss), "last_logits" (serving prefill —
-    only the next-token logits are ever needed)."""
+    only the next-token logits are ever needed).  ``diff=False`` is an
+    inference forward: MoE layers run dropless there, as in serving;
+    the training forward keeps capacity dispatch."""
     """Full-sequence forward.  batch:
       dense/moe/ssm/hybrid: {"tokens": [B, S]}
       vlm:    {"tokens": [B, S - n_patches], "patches": [B, n_patches, d]}
@@ -382,6 +557,14 @@ def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray],
                 return xx + h
             return _maybe_remat(blk, cfg)(xc), None
         x, _ = _layer_loop(cfg, body, x, params["blocks"], cfg.n_layers,
+                           allow_group=True)
+    elif cfg.family == "moe_hybrid":
+        def body(xc, bp):
+            return _maybe_remat(
+                lambda xx: _hybrid_moe_period(bp, cfg, xx, positions,
+                                              diff=diff), cfg)(xc), None
+        x, _ = _layer_loop(cfg, body, x, params["blocks"],
+                           cfg.n_layers // len(cfg.layer_period),
                            allow_group=True)
     elif cfg.family == "hybrid":
         def body(xc, gp):
@@ -452,7 +635,7 @@ def init_cache(cfg: ArchConfig, rules: Rules, batch_size: int, s_max: int,
     tp = max(1, rules.tp_degree)
     kv_ax = ("tp", None) if kv and kv % tp == 0 else         ((None, "tp") if hd and hd % tp == 0 else (None, None))
 
-    kv8 = cfg.serve_kv_bits == 8 and cfg.family in ("dense", "moe", "vlm")
+    kv8 = cfg.serve_kv_bits == 8 and cfg.family in SLOT_STATE_FAMILIES
     kv_dtype = jnp.int8 if kv8 else cfg.dtype
 
     def kvc(n_layers, s):
@@ -486,16 +669,25 @@ def init_cache(cfg: ArchConfig, rules: Rules, batch_size: int, s_max: int,
         cache["cross_v"] = ini.zeros(
             (cfg.n_dec_layers, b, s_max, kv, hd),
             (None, "batch", None) + kv_ax)
-    elif cfg.family == "ssm":
+    elif cfg.family in ("ssm", "moe_hybrid"):
+        n_mamba = cfg.n_layers
+        if cfg.family == "moe_hybrid":
+            # attention layers keep KV rows, Mamba2 layers conv and SSM
+            # state; each kind stacked in layer order
+            n_mamba = sum(t == "mamba" for t in cfg.layer_types)
+            if n_mamba < cfg.n_layers:
+                cache.update(kvc(cfg.n_layers - n_mamba, s_max))
         scfg = _ssm_cfg(cfg)
-        cache["conv"] = ini.zeros(
-            (cfg.n_layers, b, scfg.d_conv - 1, scfg.conv_channels),
-            (None, "batch", None, "tp"))
-        nh = scfg.n_heads
-        h_ax = ("tp", None, None) if nh % tp == 0 else (None, "tp", None)
-        cache["ssm"] = ini.zeros(
-            (cfg.n_layers, b, nh, scfg.d_state, scfg.head_dim),
-            (None, "batch") + h_ax, dtype=jnp.float32)
+        if n_mamba:
+            cache["conv"] = ini.zeros(
+                (n_mamba, b, scfg.d_conv - 1, scfg.conv_channels),
+                (None, "batch", None, "tp"))
+            nh = scfg.n_heads
+            h_ax = ("tp", None, None) if nh % tp == 0 \
+                else (None, "tp", None)
+            cache["ssm"] = ini.zeros(
+                (n_mamba, b, nh, scfg.d_state, scfg.head_dim),
+                (None, "batch") + h_ax, dtype=jnp.float32)
     elif cfg.family == "hybrid":
         n_groups = cfg.n_layers // 3
         n_tail = cfg.n_layers - 3 * n_groups
@@ -551,7 +743,7 @@ def _decode_attn_ring(bp, cfg: ArchConfig, x, k_cache, v_cache, index,
 
 
 def decode_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
-                advance=None):
+                advance=None, *, moe_stats: bool = False):
     """One decode step.  tokens [B, 1] int32; returns (logits, new cache).
 
     The cache pytree layout matches ``init_cache`` (stacked layer axis);
@@ -561,17 +753,24 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
     new cache always carries the normalized [B] form so the pytree
     signature stays stable under jit.
 
-    ``advance`` [B] int32 (optional, KV-cache families only): slots
-    with 0 neither write KV nor move their index — they are mid-prefill
-    in a mixed continuous-batching iteration and their logits are
-    discarded.  Omitted means every slot advances (the classic step).
+    ``advance`` [B] int32 (optional, ``slot_state`` families only):
+    slots with 0 neither write their state (KV rows, SSM and conv
+    state) nor move their index — they are mid-prefill in a mixed
+    continuous-batching iteration and their logits are discarded.
+    Omitted means every slot advances (the classic step).
+
+    ``moe_stats=True`` returns (logits, new cache, stats): the MoE
+    layers' routing statistics, int32 [n_moe_layers, 3] in the order
+    of ``layers.MOE_STATS`` (None for a model without MoE layers).
+    MoE layers run dropless here.
     """
     b = tokens.shape[0]
     index = jnp.broadcast_to(jnp.asarray(cache["index"], jnp.int32), (b,))
-    if advance is not None and cfg.family not in ("dense", "moe", "vlm"):
+    if advance is not None and not slot_state(cfg):
         raise ValueError(
             f"advance mask unsupported for family {cfg.family!r}")
     x = _embed(cfg, params, tokens)
+    stats = None
 
     if cfg.family in ("dense", "moe", "vlm"):
         acfg = _attn_cfg(cfg)
@@ -592,11 +791,13 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
             h, rest = outs[0], outs[1:]
             y = xc + h
             z = L.rmsnorm_apply(bp["ln_mlp"], y)
+            st = None
             if moe:
-                y = y + L.moe_apply(bp["moe"], _moe_cfg(cfg), z)
+                m, st = L.moe_dropless(bp["moe"], _moe_cfg(cfg), z)
+                y = y + m
             else:
                 y = y + L.mlp_apply(bp["mlp"], z, act=cfg.act)
-            return (y,) + rest
+            return (y,) + rest + (st,)
 
         if me > 1:
             n_groups = cfg.n_layers // me
@@ -608,28 +809,30 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
                 nks, nvs = [], []
                 y = xc
                 for i in range(me):
-                    y, nk, nv = one(bps[i], y, kc[i], vc[i],
-                                    moe=(i == 0))[:3]
+                    outs = one(bps[i], y, kc[i], vc[i], moe=(i == 0))
+                    y, nk, nv = outs[:3]
+                    if i == 0:
+                        st = outs[-1]
                     nks.append(nk)
                     nvs.append(nv)
-                return y, (jnp.stack(nks), jnp.stack(nvs))
+                return y, (jnp.stack(nks), jnp.stack(nvs), st)
 
             xs = tuple([params["blocks"]]
                        + [params[f"blocks_dense{i}"] for i in range(1, me)]
                        + [kg, vg])
-            x, (nk, nv) = _layer_loop(cfg, body, x, xs, n_groups)
+            x, (nk, nv, stats) = _layer_loop(cfg, body, x, xs, n_groups)
             nk = nk.reshape(cache["k"].shape)
             nv = nv.reshape(cache["v"].shape)
             new_cache = dict(cache, k=nk, v=nv, index=index + bump)
         elif kv8:
             def body(xc, sl):
                 bp, kc, vc, ks, vs = sl
-                y, nk, nv, nks, nvs = one(bp, xc, kc, vc,
-                                          moe=(cfg.family == "moe"),
-                                          ks=ks, vs=vs)
-                return y, (nk, nv, nks, nvs)
+                y, nk, nv, nks, nvs, st = one(bp, xc, kc, vc,
+                                              moe=(cfg.family == "moe"),
+                                              ks=ks, vs=vs)
+                return y, (nk, nv, nks, nvs, st)
 
-            x, (nk, nv, nks, nvs) = _layer_loop(
+            x, (nk, nv, nks, nvs, stats) = _layer_loop(
                 cfg, body, x, (params["blocks"], cache["k"], cache["v"],
                                cache["k_scale"], cache["v_scale"]),
                 cfg.n_layers)
@@ -638,13 +841,21 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
         else:
             def body(xc, sl):
                 bp, kc, vc = sl
-                y, nk, nv = one(bp, xc, kc, vc, moe=(cfg.family == "moe"))
-                return y, (nk, nv)
+                y, nk, nv, st = one(bp, xc, kc, vc,
+                                    moe=(cfg.family == "moe"))
+                return y, (nk, nv, st)
 
-            x, (nk, nv) = _layer_loop(
+            x, (nk, nv, stats) = _layer_loop(
                 cfg, body, x, (params["blocks"], cache["k"], cache["v"]),
                 cfg.n_layers)
             new_cache = dict(cache, k=nk, v=nv, index=index + bump)
+
+    elif cfg.family == "moe_hybrid":
+        x, new, stats = _hybrid_moe_cached(cfg, params, cache, x,
+                                           decode=True, advance=advance)
+        bump = 1 if advance is None else \
+            jnp.broadcast_to(jnp.asarray(advance, jnp.int32), (b,))
+        new_cache = dict(cache, **new, index=index + bump)
 
     elif cfg.family == "encdec":
         acfg = _attn_cfg(cfg)
@@ -733,6 +944,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
     else:
         raise ValueError(cfg.family)
 
+    if moe_stats:
+        return _unembed(cfg, params, x), new_cache, stats
     return _unembed(cfg, params, x), new_cache
 
 
@@ -758,17 +971,22 @@ def reset_slot(cache, slot):
 
 def _prefill_forward(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
                      n_valid: jnp.ndarray):
-    """Shared chunked teacher-forcing core for the KV-cache families:
-    returns (final hidden states [B, C, d], new cache).  ``prefill_step``
-    discards the hidden states (cache-only prompt replay);
-    ``verify_step`` unembeds them (speculative verification needs the
-    logits at every fed position)."""
-    if cfg.family not in ("dense", "moe", "vlm"):
+    """Shared chunked teacher-forcing core for the ``slot_state``
+    families: returns (final hidden states [B, C, d], new cache, MoE
+    routing stats or None).  ``prefill_step`` discards the hidden
+    states (cache-only prompt replay); ``verify_step`` unembeds them
+    (speculative verification needs the logits at every fed
+    position)."""
+    if not slot_state(cfg):
         raise ValueError(f"prefill_step: unsupported family {cfg.family}")
     b = tokens.shape[0]
     index = jnp.broadcast_to(jnp.asarray(cache["index"], jnp.int32), (b,))
     n_valid = jnp.asarray(n_valid, jnp.int32)
     x = _embed(cfg, params, tokens)
+    if cfg.family == "moe_hybrid":
+        x, new, stats = _hybrid_moe_cached(cfg, params, cache, x,
+                                           decode=False, n_valid=n_valid)
+        return x, dict(cache, **new, index=index + n_valid), stats
     acfg = _attn_cfg(cfg)          # same attention config as decode_step
     me = cfg.moe_every if cfg.family == "moe" else 1
     kv8 = "k_scale" in cache
@@ -781,11 +999,13 @@ def _prefill_forward(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
         h, rest = outs[0], outs[1:]
         y = xc + h
         z = L.rmsnorm_apply(bp["ln_mlp"], y)
+        st = None
         if moe:
-            y = y + L.moe_apply(bp["moe"], _moe_cfg(cfg), z)
+            m, st = L.moe_dropless(bp["moe"], _moe_cfg(cfg), z)
+            y = y + m
         else:
             y = y + L.mlp_apply(bp["mlp"], z, act=cfg.act)
-        return (y,) + rest
+        return (y,) + rest + (st,)
 
     if me > 1:
         n_groups = cfg.n_layers // me
@@ -797,47 +1017,50 @@ def _prefill_forward(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
             nks, nvs = [], []
             y = xc
             for i in range(me):
-                y, nk, nv = one(bps[i], y, kc[i], vc[i], moe=(i == 0))[:3]
+                outs = one(bps[i], y, kc[i], vc[i], moe=(i == 0))
+                y, nk, nv = outs[:3]
+                if i == 0:
+                    st = outs[-1]
                 nks.append(nk)
                 nvs.append(nv)
-            return y, (jnp.stack(nks), jnp.stack(nvs))
+            return y, (jnp.stack(nks), jnp.stack(nvs), st)
 
         xs = tuple([params["blocks"]]
                    + [params[f"blocks_dense{i}"] for i in range(1, me)]
                    + [kg, vg])
-        x, (nk, nv) = _layer_loop(cfg, body, x, xs, n_groups)
+        x, (nk, nv, stats) = _layer_loop(cfg, body, x, xs, n_groups)
         nk = nk.reshape(cache["k"].shape)
         nv = nv.reshape(cache["v"].shape)
-        return x, dict(cache, k=nk, v=nv, index=index + n_valid)
+        return x, dict(cache, k=nk, v=nv, index=index + n_valid), stats
     if kv8:
         def body(xc, sl):
             bp, kc, vc, ks, vs = sl
-            y, nk, nv, nks, nvs = one(bp, xc, kc, vc,
-                                      moe=(cfg.family == "moe"),
-                                      ks=ks, vs=vs)
-            return y, (nk, nv, nks, nvs)
+            y, nk, nv, nks, nvs, st = one(bp, xc, kc, vc,
+                                          moe=(cfg.family == "moe"),
+                                          ks=ks, vs=vs)
+            return y, (nk, nv, nks, nvs, st)
 
-        x, (nk, nv, nks, nvs) = _layer_loop(
+        x, (nk, nv, nks, nvs, stats) = _layer_loop(
             cfg, body, x, (params["blocks"], cache["k"], cache["v"],
                            cache["k_scale"], cache["v_scale"]),
             cfg.n_layers)
         return x, dict(cache, k=nk, v=nv, k_scale=nks, v_scale=nvs,
-                       index=index + n_valid)
+                       index=index + n_valid), stats
 
     def body(xc, sl):
         bp, kc, vc = sl
-        y, nk, nv = one(bp, xc, kc, vc, moe=(cfg.family == "moe"))
-        return y, (nk, nv)
+        y, nk, nv, st = one(bp, xc, kc, vc, moe=(cfg.family == "moe"))
+        return y, (nk, nv, st)
 
-    x, (nk, nv) = _layer_loop(
+    x, (nk, nv, stats) = _layer_loop(
         cfg, body, x, (params["blocks"], cache["k"], cache["v"]),
         cfg.n_layers)
-    return x, dict(cache, k=nk, v=nv, index=index + n_valid)
+    return x, dict(cache, k=nk, v=nv, index=index + n_valid), stats
 
 
 def prefill_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
-                 n_valid: jnp.ndarray):
-    """One chunked-prefill step for the KV-cache families.
+                 n_valid: jnp.ndarray, *, moe_stats: bool = False):
+    """One chunked-prefill step for the ``slot_state`` families.
 
     tokens [B, C] int32 — a teacher-forced prompt chunk per slot,
     zero-padded; n_valid [B] int32 in [0, C] says how many columns of
@@ -847,12 +1070,18 @@ def prefill_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
     decoders for ceil(P/C) iterations instead of P.  Returns the new
     cache only — prefill logits are never sampled.
 
-    Families with recurrent state (ssm/hybrid) and encdec replay
-    prompts one token per ``decode_step`` instead (chunk = 1): their
-    per-token state update is inherently sequential.
+    The moe_hybrid family prefills its Mamba2 layers through the
+    chunked SSD scan from the slot's state (padded columns take dt = 0
+    and leave the state as the last real column left it).  The other
+    recurrent families (ssm/hybrid) and encdec replay prompts one
+    token per ``decode_step`` instead (chunk = 1).
+
+    ``moe_stats=True`` returns (new cache, MoE routing stats or None)
+    as ``decode_step`` does.
     """
-    _, new_cache = _prefill_forward(cfg, params, cache, tokens, n_valid)
-    return new_cache
+    _, new_cache, stats = _prefill_forward(cfg, params, cache, tokens,
+                                           n_valid)
+    return (new_cache, stats) if moe_stats else new_cache
 
 
 def verify_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
@@ -877,7 +1106,7 @@ def verify_step(cfg: ArchConfig, params, cache, tokens: jnp.ndarray,
     slot's ``n_valid`` return garbage logits (their KV writes drop out
     of bounds) — callers only read accepted prefixes.
     """
-    x, new_cache = _prefill_forward(cfg, params, cache, tokens, n_valid)
+    x, new_cache, _ = _prefill_forward(cfg, params, cache, tokens, n_valid)
     return _unembed(cfg, params, x), new_cache
 
 
@@ -920,7 +1149,8 @@ def rollback_slot(cache, slot, n):
 
 
 def prefill_slot(cfg: ArchConfig, params, cache, slot,
-                 tokens: jnp.ndarray, n_valid: jnp.ndarray):
+                 tokens: jnp.ndarray, n_valid: jnp.ndarray, *,
+                 moe_stats: bool = False):
     """Chunked prefill of a SINGLE batch slot.
 
     ``slot`` is a traced int32 scalar (one compiled program serves
@@ -930,13 +1160,16 @@ def prefill_slot(cfg: ArchConfig, params, cache, slot,
     per-slot by construction, so a request's prompt replay runs the
     exact same compiled program — on the same single-row operands —
     whether it opens a wave or joins one mid-flight: bit-exactness
-    across wave compositions is structural.
+    across wave compositions is structural.  ``moe_stats=True``
+    returns (new cache, MoE routing stats or None).
     """
     slot = jnp.asarray(slot, jnp.int32)
     sub = {name: jax.lax.dynamic_slice_in_dim(
         leaf, slot, 1, axis=0 if name == "index" else 1)
         for name, leaf in cache.items()}
-    new = prefill_step(cfg, params, sub, tokens, n_valid)
-    return {name: jax.lax.dynamic_update_slice_in_dim(
+    new, stats = prefill_step(cfg, params, sub, tokens, n_valid,
+                              moe_stats=True)
+    merged = {name: jax.lax.dynamic_update_slice_in_dim(
         cache[name], new[name], slot, axis=0 if name == "index" else 1)
         for name in cache}
+    return (merged, stats) if moe_stats else merged

@@ -23,16 +23,19 @@ packed kernels execute at high occupancy":
     quantum of iterations and pulls fitting queued requests into
     freed slots every iteration, so arrivals between steps join the
     running wave instead of waiting for the next boundary;
-  * prompt replay is split from decode: KV-cache families
-    (dense/moe/vlm) replay prompts through a chunked *prefill step*
-    (``models.prefill_slot``, ``prefill_chunk`` teacher-forced tokens
-    per slot per iteration), and prefill piggybacks on decode — both
-    run in the same iteration on disjoint slots, the decode advance
-    mask freezing mid-prefill slots — so a joiner replays its prompt
-    in ceil(P/C) iterations without ever stalling its decoding
-    neighbours;
-    recurrent-state families (ssm/hybrid) and encdec replay
-    token-at-a-time through ``decode_step``.  Prefill and decode step
+  * prompt replay is split from decode: a model whose per-slot state
+    takes the advance mask and chunked prefill (``models.slot_state``:
+    the KV-cache families and the Mamba2/attention moe_hybrid) replays
+    prompts through a chunked *prefill step* (``models.prefill_slot``,
+    ``prefill_chunk`` teacher-forced tokens per slot per iteration),
+    and prefill piggybacks on decode — both run in the same iteration
+    on disjoint slots, the decode advance mask freezing mid-prefill
+    slots — so a joiner replays its prompt in ceil(P/C) iterations
+    without ever stalling its decoding neighbours; the others
+    (ssm/hybrid, encdec) replay token-at-a-time through
+    ``decode_step``.  MoE layers serve dropless, and their routing
+    statistics come back with the sampled logits (``EngineMetrics``
+    ``moe_*``).  Prefill and decode step
     times feed *separate* EMAs — admission control estimates from the
     decode EMA of the request's own bucket, never a prefill-skewed
     global max.
@@ -274,7 +277,8 @@ class Engine:
                  min_size: int = 1024, pad_token: int = 0):
         import jax
 
-        from repro.models import decode_step, prefill_slot, reset_slot
+        from repro.models import (decode_step, prefill_slot, reset_slot,
+                                  slot_state)
 
         self.cfg = cfg
         self.params = params
@@ -308,10 +312,10 @@ class Engine:
         if prefill_chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got "
                              f"{prefill_chunk}")
-        #: teacher-forced tokens per prefill iteration; recurrent-state
-        #: families replay token-at-a-time through decode_step instead
-        self.prefill_chunk = prefill_chunk \
-            if cfg.family in ("dense", "moe", "vlm") else 1
+        #: teacher-forced tokens per prefill iteration; a model whose
+        #: state takes no chunked prefill replays token-at-a-time
+        #: through decode_step instead
+        self.prefill_chunk = prefill_chunk if slot_state(cfg) else 1
         if wave_quantum < 1:
             raise ValueError(f"wave_quantum must be >= 1, got "
                              f"{wave_quantum}")
@@ -320,16 +324,33 @@ class Engine:
         # one decode fn for every decode everywhere (pure-decode,
         # mixed prefill+decode, warmup, fallback): the advance mask is
         # an *input*, so compositions share a single compiled function
-        # and per-request results cannot depend on wave makeup
-        use_adv = cfg.family in ("dense", "moe", "vlm")
-        self._dec = jax.jit(
-            lambda p, c, t, adv: decode_step(
-                cfg, p, c, t, advance=adv if use_adv else None))
+        # and per-request results cannot depend on wave makeup.  It
+        # returns (logits, cache, MoE routing stats or None); only a
+        # model with MoE layers is asked for the statistics
+        use_adv = slot_state(cfg)
+        moe = cfg.n_experts > 0
+
+        def dec(p, c, t, adv):
+            adv = adv if use_adv else None
+            if moe:
+                return decode_step(cfg, p, c, t, advance=adv,
+                                   moe_stats=True)
+            return decode_step(cfg, p, c, t, advance=adv) + (None,)
+
+        def pre(p, c, s, t, nv):
+            if moe:
+                return prefill_slot(cfg, p, c, s, t, nv, moe_stats=True)
+            return prefill_slot(cfg, p, c, s, t, nv), None
+
+        self._dec = jax.jit(dec)
         # prefill is per-slot: one [1, C] program reused for every
         # slot, wave start and mid-wave join alike, so a prompt's
-        # replay cost and numerics never depend on wave composition
-        self._pre = jax.jit(
-            lambda p, c, s, t, nv: prefill_slot(cfg, p, c, s, t, nv))
+        # replay cost and numerics never depend on wave composition.
+        # It returns (cache, MoE routing stats or None)
+        self._pre = jax.jit(pre)
+        #: MoE routing statistics of dispatched programs, read back
+        #: with the next sampled logits
+        self._moe_pending: List[Any] = []
         self._reset = jax.jit(lambda c, slot: reset_slot(c, slot))
         # speculative decoding: a W-low/A-low self-speculation draft of
         # the SAME checkpoint proposes spec_k tokens per round and the
@@ -373,16 +394,20 @@ class Engine:
 
     def _qparams(self, rows: int) -> Any:
         """Packed parameters planned for a ``rows``-row decode batch
-        (memoized — buckets sharing a batch width share the tree)."""
+        (memoized — buckets sharing a batch width share the tree).
+        ``params`` may be a ``models.LayerStream``: the layers are then
+        made and packed one at a time."""
         from repro.models import serve_params
         if rows not in self._qparams_by_rows:
-            self._qparams_by_rows[rows] = serve_params(
-                self.params, bits=self.weight_bits, min_size=self.min_size,
-                compute=self.compute, act_bits=self.act_bits,
-                conv_bseg=(self.compute == "sdv"
-                           and self.conv_datapath == "bseg"),
-                plan_policy=self.plan_policy, plan_cache=self.plan_cache,
-                rows=rows)
+            with SPANS.span("engine.pack"):
+                self._qparams_by_rows[rows] = serve_params(
+                    self.params, bits=self.weight_bits,
+                    min_size=self.min_size, compute=self.compute,
+                    act_bits=self.act_bits,
+                    conv_bseg=(self.compute == "sdv"
+                               and self.conv_datapath == "bseg"),
+                    plan_policy=self.plan_policy,
+                    plan_cache=self.plan_cache, rows=rows)
         return self._qparams_by_rows[rows]
 
     def packed_params(self, batch: int) -> Any:
@@ -459,11 +484,11 @@ class Engine:
             self.faults.maybe_fail_compile(bucket.key)
         toks = jnp.full((st.bucket.batch, 1), self.pad_token, jnp.int32)
         ones = jnp.ones((st.bucket.batch,), jnp.int32)
-        logits, _ = self._dec(st.qparams, st.cache0, toks, ones)  # compile
+        logits = self._dec(st.qparams, st.cache0, toks, ones)[0]  # compile
         jax.block_until_ready(logits)
         self._compile_aux(st)
         t0 = self.clock()
-        logits, _ = self._dec(st.qparams, st.cache0, toks, ones)  # measure
+        logits = self._dec(st.qparams, st.cache0, toks, ones)[0]  # measure
         jax.block_until_ready(logits)
         st.decode_s = max(self.clock() - t0, 1e-9)
         st.warmed = True
@@ -489,8 +514,8 @@ class Engine:
             # program is needed even at chunk 1
             ptoks = jnp.full((1, self.prefill_chunk), self.pad_token,
                              jnp.int32)
-            cache = self._pre(st.qparams, st.cache0, 0, ptoks,
-                              jnp.ones((1,), jnp.int32))
+            cache, _ = self._pre(st.qparams, st.cache0, 0, ptoks,
+                                 jnp.ones((1,), jnp.int32))
             jax.block_until_ready(cache["index"])
         cache = self._reset(st.cache0, 0)
         jax.block_until_ready(cache["index"])
@@ -963,9 +988,11 @@ class Engine:
                     # prefill feeds the TARGET cache only: the draft
                     # forks it per round (self-speculation shares the
                     # layout), so spec mode pays no second prefill pass
-                    cache = self._pre(st.qparams, cache, slot,
-                                      jnp.asarray(toks),
-                                      jnp.asarray([n], np.int32))
+                    cache, stats = self._pre(st.qparams, cache, slot,
+                                             jnp.asarray(toks),
+                                             jnp.asarray([n], np.int32))
+                    if stats is not None:
+                        self._moe_pending.append(stats)
                     s.fed += n
                 # sync INSIDE the timed loop: the prefill EMA must
                 # include device time
@@ -999,8 +1026,11 @@ class Engine:
             adv = np.ones((b,), np.int32)
             for slot in pref_slots:     # mid-prefill slots: no KV write,
                 adv[slot] = 0           # no index move, logits discarded
-            logits, cache = self._dec(st.qparams, st.cache,
-                                      jnp.asarray(toks), jnp.asarray(adv))
+            logits, cache, stats = self._dec(st.qparams, st.cache,
+                                             jnp.asarray(toks),
+                                             jnp.asarray(adv))
+            if stats is not None:
+                self._moe_pending.append(stats)
             # sync INSIDE the timed loop: per-step wall clock and
             # completion latencies must include device time
             jax.block_until_ready(logits)
@@ -1014,9 +1044,18 @@ class Engine:
                 decoding: List[Tuple[int, Session]],
                 logits: Any) -> List[Completion]:
         """Read a decode step's logits back, take each decoding slot's
-        greedy token, and retire the sessions that are done."""
+        greedy token, and retire the sessions that are done.  MoE
+        routing statistics of the programs dispatched since the last
+        sample come back in the same transfer."""
         bucket, table = st.bucket, st.sessions
-        last = np.asarray(logits[:, -1, :self.cfg.vocab])
+        last = logits[:, -1, :self.cfg.vocab]
+        if self._moe_pending:
+            import jax
+            last, got = jax.device_get((last, self._moe_pending))
+            self._moe_pending = []
+            for a in got:
+                self.metrics.record_moe(a, self.cfg.n_experts)
+        last = np.asarray(last)
         finish_t = self.clock()
         completions: List[Completion] = []
         emitted = 0
@@ -1267,7 +1306,7 @@ class Engine:
         import jax.numpy as jnp
         toks = jnp.full((st.bucket.batch, 1), self.pad_token, jnp.int32)
         ones = jnp.ones((st.bucket.batch,), jnp.int32)
-        logits, _ = self._dec(st.qparams, st.cache0, toks, ones)
+        logits = self._dec(st.qparams, st.cache0, toks, ones)[0]
         jax.block_until_ready(logits)
         self._compile_aux(st, spec=False)
         st.warmed = True

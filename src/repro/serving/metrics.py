@@ -395,6 +395,12 @@ class EngineMetrics:
     wave_wall_s: float = 0.0
     busy_slot_steps: int = 0        # occupied KV slots summed over steps
     slot_steps: int = 0             # batch-width slots summed over steps
+    # -- MoE routing (dropless layers), summed over MoE layer calls
+    moe_calls: int = 0              # MoE layer calls (layers x programs)
+    moe_rows: int = 0               # routed (token, expert) rows
+    moe_peak_rows: int = 0          # rows of each call's busiest expert
+    moe_experts_touched: int = 0    # experts given at least one row
+    moe_load_peak: float = 0.0      # busiest rows over rows / experts
     per_bucket: Dict[str, Dict[str, Any]] = dataclasses.field(
         default_factory=dict)
     started_t: Optional[float] = None
@@ -442,6 +448,18 @@ class EngineMetrics:
 
     def record_join(self) -> None:
         self.midwave_joins += 1
+
+    def record_moe(self, stats: Any, n_experts: int) -> None:
+        """One program's MoE routing statistics: rows of [rows routed,
+        rows of the busiest expert, experts touched], one per MoE
+        layer call (``models.layers.MOE_STATS``)."""
+        for rows, peak, touched in stats:
+            self.moe_calls += 1
+            self.moe_rows += int(rows)
+            self.moe_peak_rows += int(peak)
+            self.moe_experts_touched += int(touched)
+            if rows:
+                self.moe_load_peak += int(peak) * n_experts / int(rows)
 
     def record_decode_launch(self, tokens_emitted: int) -> None:
         """One plain (non-speculative) decode-step launch and the
@@ -599,5 +617,12 @@ class EngineMetrics:
                       # and wall_s (ended waves only) leave out
                       "occupancy": (self.busy_slot_steps / self.slot_steps
                                     if self.slot_steps else 0.0)},
+            "moe": {"calls": self.moe_calls, "rows": self.moe_rows,
+                    "peak_rows": self.moe_peak_rows,
+                    "experts_touched": self.moe_experts_touched,
+                    # the busiest expert's rows over an even share,
+                    # averaged over calls (1.0: even routing)
+                    "load_peak": (self.moe_load_peak / self.moe_calls
+                                  if self.moe_calls else 0.0)},
             "buckets": self.per_bucket,
         }

@@ -9,8 +9,9 @@ at import: only one process may load the TPU library), and the tests
 skip when it cannot be described.  Shapes: gemma-2b decode and prefill
 GEMMs on the planner's two W4A8 plans (int32 n=2, DSP48E2 n=3),
 granite-8b's projections at the tiles ``ops.sdv_blocks`` picks,
-UltraNet's 416x416 conv stages, the mamba2-130m short conv, and the
-memory-packed ``quant_matmul``.
+UltraNet's 416x416 conv stages, the mamba2-130m short conv, the
+memory-packed ``quant_matmul``, and granite-4.0-h-small's grouped
+expert matmul (``moe_gmm``).
 """
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.datapath import DSP48E2, INT32, plan_bseg, plan_sdv
 from repro.kernels import bseg_common, ops
 from repro.kernels.bseg_conv1d import bseg_conv1d
+from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.quant_matmul import quant_matmul
 from repro.kernels.sdv_matmul import sdv_matmul
 from repro.kernels.sdv_matvec import sdv_matvec
@@ -144,4 +146,17 @@ def test_quant_matmul_compiles(compile_tpu, rows):
         lambda x, w, s: quant_matmul(x, w, s, w=4, interpret=False),
         ((rows, 2048), jnp.bfloat16), ((2048, 16384 // 8), jnp.int32),
         ((16384,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [320, 640])
+@pytest.mark.parametrize("k,n", [(4096, 768), (768, 4096)])
+def test_moe_gmm_compiles(compile_tpu, rows, k, n):
+    """granite-4.0-h-small's 72 experts: a 32-row decode step and a
+    64-token prefill chunk at top-10 (320 and 640 routed rows), the
+    gate/up and the down projections."""
+    hlo = compile_tpu(
+        lambda x, w, g: moe_gmm(x, w, g, interpret=False),
+        ((rows, k), jnp.bfloat16), ((72, k, n), jnp.bfloat16),
+        ((72,), jnp.int32))
     assert "tpu_custom_call" in hlo
