@@ -50,10 +50,10 @@ Dispatch table for ``packed_conv2d`` (mode -> kernel -> constraints):
   -------------  --------------------------  ------------------------------
   bseg_conv2d    kernels/bseg_conv2d         integer x; BSEG ``plan`` on
                  (cross-channel batched      any datapath — the kernel
-                 conv2d, grid B x H x        body is word-generic (1-limb
+                 conv2d, grid B x H/bh x     body is word-generic (1-limb
                  C_out/bco, fused (kh,C_in)  int32 / fp32, or 2-limb int32
-                 pipeline axis, VMEM row     for the wide DSP48E2/DSP58
-                 accumulator)                words, per
+                 pipeline axis, row block    for the wide DSP48E2/DSP58
+                 from ``bseg_blocks``)       words, per
                                              ``bseg_common.WordSpec``);
                                              stride 1, 'same' pad: odd kh
                                              and kw; ``plan.w_i <= 7``
@@ -263,6 +263,51 @@ def sdv_blocks(rows: int, k: int, g: int, plan: SDVPlan, *,
         if k % block_k:
             block_k = k  # fall back to a single K block
     return br, block_g, block_k
+
+
+#: Vregs of one ``[khc, bh*bco]`` word that a step of the BSEG conv2d
+#: kernel works on, at most (``bseg_blocks``): on a v5e a stage's time
+#: stops falling at about this size (PERF.md section 6).
+BSEG_TILE_VREGS = 64
+#: The double-buffered input, factor and output blocks of a BSEG conv2d
+#: program stay within this (v5e's scoped VMEM is 16 MiB).
+BSEG_VMEM_BYTES = 10 << 20
+
+
+def bseg_blocks(h_out: int, w_pad: int, khc: int, bco: int, n_groups: int,
+                plan: BSEGPlan) -> int:
+    """The BSEG conv2d kernel's row block ``bh``: output rows that one
+    grid program advances together through every step.
+
+    A step costs a fixed amount plus an amount per vreg of its words
+    (PERF.md section 5), and a block of rows pays the fixed part once.
+    The word of ``bh`` rows is ``[khc, bh*bco]``: ``ceil(khc/8) *
+    ceil(bh*bco/128)`` vregs per limb.  ``bh`` is the most rows whose
+    word stays within ``BSEG_TILE_VREGS`` (or within one row's word, where
+    that is larger) and whose blocks fit ``BSEG_VMEM_BYTES``: the int8
+    ``[W_pad, bh, khc]`` input, the ``[n_groups, khc, bh*bco]`` factors
+    and the int32 ``[W_pad, bh*bco]`` output, each double-buffered.  H is
+    then spread evenly over the fewest blocks of at most that many rows.
+    """
+    limbs = bseg_common.word_spec(plan).limbs
+
+    def lanes(bh):
+        return -(-(bh * bco) // 128) * 128
+
+    def vregs(bh):
+        return limbs * -(-khc // 8) * lanes(bh) // 128
+
+    def vmem(bh):
+        x_block = w_pad * -(-bh // 32) * 32 * -(-khc // 128) * 128
+        kap_block = limbs * n_groups * -(-khc // 8) * 8 * lanes(bh) * 4
+        return 2 * (x_block + kap_block + w_pad * lanes(bh) * 4)
+
+    cap = max(BSEG_TILE_VREGS, vregs(1))
+    bh = 1
+    while (bh < h_out and vregs(bh + 1) <= cap
+           and vmem(bh + 1) <= BSEG_VMEM_BYTES):
+        bh += 1
+    return -(-h_out // -(-h_out // bh))
 
 
 def sdv_matvec(x_q: jnp.ndarray, w_words: jnp.ndarray, *, plan: SDVPlan,
@@ -737,6 +782,7 @@ def _im2col_patches(x32: jnp.ndarray, kh: int, kw: int) -> jnp.ndarray:
 def packed_conv2d(x: jnp.ndarray, w_int: jnp.ndarray, *, plan: BSEGPlan,
                   mode: str = "auto", zero_point: int = 0,
                   use_kernel: bool = True, block_co: int = 128,
+                  block_h: Optional[int] = None,
                   sdv_plan: Optional[SDVPlan] = None) -> jnp.ndarray:
     """Stride-1 'same'-pad conv2d with kernel dispatch.
 
@@ -751,6 +797,8 @@ def packed_conv2d(x: jnp.ndarray, w_int: jnp.ndarray, *, plan: BSEGPlan,
       mode: a row of the dispatch table, or ``"auto"``.
       block_co: output-channel block size for the conv2d kernel
         (downgraded to C_out when not divisible).
+      block_h: output rows per conv2d kernel program; ``None`` takes
+        ``bseg_blocks``'s choice for the shape.
       sdv_plan: optional SDV plan for the im2col route (the planner
         picks one per layer); defaults to the plan derived from the
         BSEG widths.  An unsigned-element-domain override
@@ -814,8 +862,12 @@ def packed_conv2d(x: jnp.ndarray, w_int: jnp.ndarray, *, plan: BSEGPlan,
     bco = min(block_co, c_out)
     if c_out % bco:
         bco = c_out
+    if block_h is None:
+        block_h = bseg_blocks(h, x_pad.shape[2], kh * c_in, bco, n_groups,
+                              plan)
     y = bseg2d_kernel.bseg_conv2d(x_pad, kappa, plan=plan, h_out=h,
-                                  w_out=w, bco=bco, interpret=_on_cpu())
+                                  w_out=w, bh=min(block_h, h), bco=bco,
+                                  interpret=_on_cpu())
     if zero_point:
         y = y - zero_point * tap_sum[None, None, None, :]
     return y

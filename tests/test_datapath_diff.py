@@ -202,15 +202,27 @@ _CONV_EXEC_PLANS = [p for p in planner.enumerate_plans(_CONV_EXEC_LAYER)
                     if isinstance(p, BSEGPlan)]
 
 
+#: the row block of the conv2d kernel on the planner's W4A4 plan of the
+#: int32, fp32m and dsp48e2 words: 2 rows (the 3-row frame is not a
+#: multiple, so the last block is padded) and 3 rows, one output
+#: channel per block (bco < C_out)
+_CONV_BLOCK_CASES = [(plan_bseg(DATAPATHS[n], 4, 4), bh)
+                     for n in ("int32", "fp32m", "dsp48e2") for bh in (2, 3)]
+
+
 @pytest.mark.parametrize(
-    "plan", _CONV_EXEC_PLANS,
-    ids=[_plan_id(p) for p in _CONV_EXEC_PLANS])
-def test_conv2d_datapath_diff(plan):
+    "plan,block_h",
+    [(p, None) for p in _CONV_EXEC_PLANS] + _CONV_BLOCK_CASES,
+    ids=[_plan_id(p) for p in _CONV_EXEC_PLANS]
+    + [f"{_plan_id(p)}-bh{bh}" for p, bh in _CONV_BLOCK_CASES])
+def test_conv2d_datapath_diff(plan, block_h):
     """Every enumerable W4A4 BSEG conv plan through ``packed_conv2d``
     (auto route) == the integer conv oracle — both signedness regimes
-    (zero point on/off, alternating deterministically per plan)."""
+    (zero point on/off, alternating deterministically per plan, and
+    per row block where one is given)."""
     ly = _CONV_EXEC_LAYER
-    zp = (1 << (plan.w_i - 1)) if (plan.lane + plan.n_k) % 2 else 0
+    zp = ((1 << (plan.w_i - 1)) if (plan.lane + plan.n_k + (block_h or 0)) % 2
+          else 0)
     rng = np.random.default_rng(zlib.crc32(_plan_id(plan).encode()))
     x = jnp.asarray(rng.integers(-zp, (1 << plan.w_i) - zp,
                                  (1, ly.h, ly.w, ly.c_in)), jnp.int32)
@@ -220,7 +232,9 @@ def test_conv2d_datapath_diff(plan):
                     jnp.int8)
     route = ops.select_conv_route(x.shape, w.shape, plan=plan)
     assert route != "ref", plan        # the gap stays closed
-    y = ops.packed_conv2d(x, w, plan=plan, mode="auto", zero_point=zp)
+    blocks = {} if block_h is None else {"block_h": block_h, "block_co": 1}
+    y = ops.packed_conv2d(x, w, plan=plan, mode="auto", zero_point=zp,
+                          **blocks)
     want = np.asarray(ref.conv2d_int_ref(x, w))
     assert (np.asarray(y) == want).all(), (plan, route)
 

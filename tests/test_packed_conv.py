@@ -67,13 +67,16 @@ def _check(x, w, plan, mode, zero_point=0, **kw):
     (1, 6, 6, 8, 12, 3, 3),      # H % bh != 0 fallback
     (1, 5, 7, 4, 6, 5, 5),       # 5x5 taps -> 3 tap groups
     (1, 4, 5, 6, 10, 1, 1),      # pointwise
+    (2, 8, 9, 3, 16, 3, 3, 4),   # 4-row blocks, H a multiple
+    (1, 7, 6, 4, 16, 3, 3, 3),   # 3-row blocks, the last one padded
 ])
 @pytest.mark.parametrize("mode", ["auto", "bseg_conv2d", "im2col", "ref"])
 def test_packed_conv2d_bit_exact(shape, mode):
-    b, h, w, cin, cout, kh, kw = shape
+    b, h, w, cin, cout, kh, kw, *block_h = shape
     x = _rand_x(b, h, w, cin, zero_point=8)
     wt = _rand_conv(cin, cout, kh, kw)
-    _check(x, wt, PLAN, mode, zero_point=8, block_co=8)
+    _check(x, wt, PLAN, mode, zero_point=8, block_co=8,
+           block_h=block_h[0] if block_h else None)
 
 
 @pytest.mark.parametrize("wk,wi", [(2, 2), (2, 4), (3, 3), (4, 4), (5, 2)])
@@ -87,10 +90,11 @@ def test_packed_conv2d_plan_sweep(wk, wi):
     _check(x, wt, plan, "bseg_conv2d", zero_point=zp)
 
 
-def test_packed_conv2d_unsigned_inputs_no_zero_point():
+@pytest.mark.parametrize("block_h", [None, 3])
+def test_packed_conv2d_unsigned_inputs_no_zero_point(block_h):
     x = _rand_x(1, 8, 8, 6, zero_point=0)           # already unsigned
     wt = _rand_conv(6, 9, 3, 3)
-    _check(x, wt, PLAN, "bseg_conv2d", zero_point=0)
+    _check(x, wt, PLAN, "bseg_conv2d", zero_point=0, block_h=block_h)
 
 
 def test_packed_conv2d_depthwise_route():
@@ -243,6 +247,74 @@ def test_conv2d_num_multiplies_matches_1d_accounting():
             * bseg_num_multiplies(k, w + 2 * (k // 2), PLAN)
         got = bseg_conv2d_num_multiplies(h, w, cin, cout, k, k, PLAN)
         assert got == want, (cin, cout, k)
+
+
+# ---------------------------------------------------------------------------
+# the conv2d kernel's row block (ops.bseg_blocks)
+# ---------------------------------------------------------------------------
+
+def _blocks_case(h, w, cin, cout, plan, k=3):
+    """``bseg_blocks``'s arguments as ``packed_conv2d`` builds them."""
+    n_groups = -(-k // plan.n_k)
+    n_steps = -(-(w + plan.n_k - 1) // plan.n_i)
+    need = (n_steps - 1) * plan.n_i + (n_groups - 1) * plan.n_k + plan.n_i
+    w_pad = w + k // 2 + max(k // 2, need - (w + k // 2))
+    return h, w_pad, k * cin, min(128, cout), n_groups, plan
+
+
+def _blocks_cost(bh, h, w_pad, khc, bco, n_groups, plan):
+    """(vregs of the word, VMEM bytes of the double-buffered blocks) at
+    ``bh`` rows, counted here from the docstring's rule."""
+    from repro.kernels import bseg_common
+    limbs = bseg_common.word_spec(plan).limbs
+    lanes = -(-(bh * bco) // 128) * 128
+    vregs = limbs * -(-khc // 8) * lanes // 128
+    x_block = w_pad * -(-bh // 32) * 32 * -(-khc // 128) * 128
+    kap_block = limbs * n_groups * -(-khc // 8) * 8 * lanes * 4
+    return vregs, 2 * (x_block + kap_block + w_pad * lanes * 4)
+
+
+_BLOCK_PLANS = {n: plan_bseg(DATAPATHS[n], 4, 4)
+                for n in ("int32", "fp32m", "dsp48e2", "dsp58")}
+
+
+@pytest.mark.parametrize("spec_name", sorted(_BLOCK_PLANS))
+def test_bseg_blocks_ultranet_stages(spec_name):
+    """Every 3x3 stage of the 416 frame: the fewest row blocks whose
+    word stays within the vreg target (or within one row's word) and
+    whose blocks fit the VMEM budget, H spread evenly over them."""
+    plan = _BLOCK_PLANS[spec_name]
+    for s in U.ultranet_layer_shapes(416, 416)[:-1]:
+        args = _blocks_case(s["h"], s["w"], s["cin"], s["cout"], plan)
+        bh = ops.bseg_blocks(*args)
+        assert 1 <= bh <= s["h"]
+        cap = max(ops.BSEG_TILE_VREGS, _blocks_cost(1, *args)[0])
+        fits = [r for r in range(1, s["h"] + 1)
+                if _blocks_cost(r, *args)[0] <= cap
+                and _blocks_cost(r, *args)[1] <= ops.BSEG_VMEM_BYTES]
+        assert bh in fits, (s, bh)
+        assert -(-s["h"] // bh) == -(-s["h"] // max(fits)), (s, bh)
+        assert bh == -(-s["h"] // -(-s["h"] // bh))       # spread evenly
+
+
+def test_bseg_blocks_ultranet_choice():
+    """The blocks the rule gives UltraNet 416 on the planner's INT32
+    W4A4 plan (PERF.md section 3): the thin early stages take the most
+    rows, the 64-channel stages the fewest."""
+    got = [ops.bseg_blocks(*_blocks_case(s["h"], s["w"], s["cin"],
+                                         s["cout"], PLAN))
+           for s in U.ultranet_layer_shapes(416, 416)[:-1]]
+    assert got == [52, 35, 10, 4, 4, 4, 4, 4]
+
+
+@pytest.mark.parametrize("spec_name", sorted(_BLOCK_PLANS))
+@pytest.mark.parametrize("h,w,cin,cout", [
+    (1, 1, 1, 1), (1, 9, 3, 16), (2, 3, 2, 5), (3, 5, 64, 64), (5, 2, 6, 7),
+])
+def test_bseg_blocks_tiny_frames(spec_name, h, w, cin, cout):
+    bh = ops.bseg_blocks(*_blocks_case(h, w, cin, cout,
+                                       _BLOCK_PLANS[spec_name]))
+    assert 1 <= bh <= h
 
 
 # ---------------------------------------------------------------------------

@@ -104,7 +104,9 @@ def test_sdv_default_tiles_compile(compile_tpu, monkeypatch, datapath, k, m,
 @pytest.mark.parametrize("datapath", sorted(SDV_PLANS))
 @pytest.mark.parametrize("h,cin,cout,k", [
     (416, 3, 16, 3),             # first stage
+    (208, 16, 32, 3),            # second stage
     (104, 32, 64, 3),            # body, before the last pools
+    (52, 64, 64, 3),             # body, before the last pool
     (26, 64, 64, 3),             # body at the head's resolution
     (26, 64, 36, 1),             # 1x1 head
 ])
